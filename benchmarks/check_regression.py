@@ -161,20 +161,10 @@ def measure_sharded() -> dict:
     low-core hardware is a conservative floor: real cores only help the
     sharded side.
     """
-    import subprocess
+    from benchmarks.sweep_sharded import run_probe
 
-    env = dict(os.environ,
-               XLA_FLAGS="--xla_force_host_platform_device_count=8")
-    env.setdefault("PYTHONPATH", "src")
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.sweep_sharded", "--mode", "grid",
-         "--cells", "16", "--hosts", "6", "--duration", "300",
-         "--tick", "30"],
-        capture_output=True, text=True, env=env,
-        cwd=os.path.normpath(os.path.join(os.path.dirname(__file__), "..")))
-    if proc.returncode != 0:
-        raise RuntimeError(f"sweep_sharded probe failed:\n{proc.stderr}")
-    g = json.loads(proc.stdout)
+    g = run_probe(8, "--mode", "grid", "--cells", "16", "--hosts", "6",
+                  "--duration", "300", "--tick", "30")
     n_devices = g["sharded"]["n_devices"]
     return {
         "n_cells": g["n_cells"],
@@ -202,8 +192,8 @@ def measure_kernel() -> dict:
     import time
 
     import numpy as np
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from repro.kernels.powercap import ops, ref
 
@@ -217,7 +207,7 @@ def measure_kernel() -> dict:
     ceils = np.where(active, ceils, 0.0)
     capacity = rng.uniform(0.0, 1.2, (s, h)) * np.maximum(
         ceils.sum(axis=-1), 1.0)
-    with enable_x64():
+    with jax.enable_x64(True):
         args = tuple(jnp.asarray(a) for a in (capacity, floors, ceils,
                                               weights))
         act = jnp.asarray(active)

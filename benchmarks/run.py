@@ -55,8 +55,6 @@ import argparse
 import glob
 import json
 import os
-import subprocess
-import sys
 import time
 
 #: Structured results populated by the sweep benches, dumped by ``--json``.
@@ -471,23 +469,6 @@ def sweep_grid_timed():
             f";compile:{compile_wall:.1f}s")
 
 
-def _sharded_probe(n_devices: int, *argv: str) -> dict:
-    """Run ``benchmarks.sweep_sharded`` in a subprocess with ``n_devices``
-    forced host devices (the cells mesh needs them to exist before jax
-    initializes) and parse its JSON stdout."""
-    env = dict(os.environ,
-               XLA_FLAGS="--xla_force_host_platform_device_count="
-                         f"{n_devices}")
-    env.setdefault("PYTHONPATH", "src")
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.sweep_sharded", *argv],
-        capture_output=True, text=True, env=env,
-        cwd=os.path.normpath(os.path.join(os.path.dirname(__file__), "..")))
-    if proc.returncode != 0:
-        raise RuntimeError(f"sweep_sharded probe failed:\n{proc.stderr}")
-    return json.loads(proc.stdout)
-
-
 def sweep_scale_sharded():
     """The sharded sweep engine: device scaling + the datacenter cell.
 
@@ -500,10 +481,11 @@ def sweep_scale_sharded():
     reflects however many physical cores back the virtual devices).
     Scale half: one 10,000-host / 100,000-VM-slot cell under cpc+static,
     completing end-to-end through the same path."""
-    grid = _sharded_probe(8, "--mode", "grid", "--cells", "256",
+    from benchmarks.sweep_sharded import run_probe
+    grid = run_probe(8, "--mode", "grid", "--cells", "256",
                           "--hosts", "10", "--duration", "600",
                           "--tick", "10")
-    scale = _sharded_probe(8, "--mode", "scale", "--hosts", "10000",
+    scale = run_probe(8, "--mode", "scale", "--hosts", "10000",
                            "--duration", "600", "--tick", "30")
     ARTIFACT["sweep_scale_sharded"] = {
         "n_cells": grid["n_cells"],
@@ -615,9 +597,8 @@ def main() -> None:
     # grid shapes pays trace + load instead of full recompiles (the rules
     # grid alone costs ~14 s of XLA time per cold process).
     from repro.sim.sweep import enable_compilation_cache
-    cache = enable_compilation_cache()
-    if cache:
-        print(f"# jax compilation cache: {cache}", flush=True)
+    print(f"# jax compilation cache: {enable_compilation_cache()}",
+          flush=True)
     print("name,us_per_call,derived")
     for name, fn, slow in BENCHES:
         if args.only is not None and name not in args.only:

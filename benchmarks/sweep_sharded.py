@@ -11,6 +11,11 @@ hand:
   XLA_FLAGS=--xla_force_host_platform_device_count=8 \
       PYTHONPATH=src python -m benchmarks.sweep_sharded --mode grid
 
+The probe is a CPU-only path.  On a TPU the parent already holds the chip
+and a child would contend for it, so :func:`run_probe` refuses there; the
+sharded path on real chips is ``python chip_smoke.py --four-chips``, which
+runs everything in one process.
+
 Modes:
   grid   -- an N-cell single-bucket grid run twice through
             ``run_sweep(engine="batch")``: once on 1 device, once sharded
@@ -28,8 +33,35 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
+import subprocess
 import sys
 import time
+
+_ROOT = os.path.normpath(os.path.join(os.path.dirname(__file__), ".."))
+
+
+def run_probe(n_devices: int, *argv: str) -> dict:
+    """Run this module in a child process with ``n_devices`` forced CPU
+    devices (the cells mesh needs them to exist before jax initializes)
+    and parse its JSON stdout.  Raises on a TPU backend instead of
+    spawning a second process next to the one that holds the chip."""
+    import jax
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "the sharded sweep probe forces virtual CPU devices in a child "
+            "process, which would contend for the TPU this process holds; "
+            "run `python chip_smoke.py --four-chips` for the sharded path "
+            "on chips")
+    env = dict(os.environ,
+               XLA_FLAGS=f"--xla_force_host_platform_device_count={n_devices}")
+    env.setdefault("PYTHONPATH", "src")
+    proc = subprocess.run(
+        [sys.executable, "-m", "benchmarks.sweep_sharded", *argv],
+        capture_output=True, text=True, env=env, cwd=_ROOT)
+    if proc.returncode != 0:
+        raise RuntimeError(f"sweep_sharded probe failed:\n{proc.stderr}")
+    return json.loads(proc.stdout)
 
 
 def _fingerprint(res) -> list:

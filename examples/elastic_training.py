@@ -20,11 +20,10 @@ import tempfile                                             # noqa: E402
 sys.path.insert(0, "src")
 
 import jax                                                  # noqa: E402
-from jax.sharding import (Mesh, NamedSharding,              # noqa: E402
+from jax.sharding import (AxisType, Mesh, NamedSharding,    # noqa: E402
                           PartitionSpec as P)
 
 from repro import configs                                   # noqa: E402
-from repro.launch.mesh import AxisType, make_mesh_compat    # noqa: E402
 from repro.checkpoint import Checkpointer                   # noqa: E402
 from repro.data.pipeline import SyntheticTokens             # noqa: E402
 from repro.optim.adamw import AdamW                         # noqa: E402
@@ -37,8 +36,8 @@ BATCH, SEQ = 8, 64
 
 def make_mesh(n_pods: int) -> Mesh:
     devs = jax.devices()[:n_pods * 4]
-    return make_mesh_compat((len(devs),), ("data",),
-                            devices=devs, axis_types=(AxisType.Auto,))
+    return jax.make_mesh((len(devs),), ("data",),
+                         axis_types=(AxisType.Auto,), devices=devs)
 
 
 def make_shardings(mesh, target):

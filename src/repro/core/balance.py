@@ -110,7 +110,7 @@ def _balance_caps_pallas(snapshot, av, hosts, floors, ceils, weights,
     ``(caps (H,), did)`` on the NumPy plane.
     """
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    import jax
 
     from repro.drs.arrays import dense_slot_assignment
     from repro.drs.entitlement import waterfill_dense
@@ -128,7 +128,7 @@ def _balance_caps_pallas(snapshot, av, hosts, floors, ceils, weights,
     act[0, hj, slot] = True
 
     be = backend_mod.jax_backend()
-    with enable_x64():
+    with jax.enable_x64(True):
         hosts_j = kernels.HostCols(*(jnp.asarray(c) for c in hosts))
         dense = kernels.DenseCols(jnp.asarray(fl), jnp.asarray(ce),
                                   jnp.asarray(w), jnp.asarray(act))

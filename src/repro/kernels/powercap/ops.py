@@ -12,18 +12,19 @@ pallas_enabled()``):
     (``seg_ids``) waterfill entry points: ragged host/VM counts via a CSR
     layout, one grid step per host, no ``H * J`` dense padding.
 
-Interpret-mode fallback: off-TPU (``jax.default_backend() != "tpu"``) the
-kernels run under ``pl.pallas_call(..., interpret=True)``, where they
-execute the same jnp op sequence as the lax executor and are bit-identical
-to it in float64.  ``REPRO_PALLAS_INTERPRET=0/1`` overrides the automatic
-choice (e.g. to force-compile on a TPU-less CI runner, or to interpret on
-TPU while debugging).
+Interpret mode only: the kernels run under ``pl.pallas_call(...,
+interpret=True)``, where they execute the same jnp op sequence as the lax
+executor (bit-identical to it in float64, up to a few ULPs in the fused
+balance round).  They cannot run on a TPU: the TPU compiler refuses a
+float64 ``pallas_call`` (``NotImplementedError: 64-bit types are not
+supported``), and their whole-cell ``(1, H)`` blocks are not tiled for it
+either.  On a TPU backend every driver raises :class:`PallasUnsupported`
+up front instead of failing deep inside a compile.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 
 import numpy as np
 
@@ -34,30 +35,32 @@ from repro.core import kernels as core_kernels
 from repro.kernels.powercap import kernel
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+class PallasUnsupported(NotImplementedError):
+    """The ``jax-pallas`` executor was asked to run on a TPU backend."""
 
 
-def interpret_mode() -> bool:
-    """Whether the kernels run in interpret mode (auto: off-TPU)."""
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env.lower() not in ("0", "false", "no")
-    return not _on_tpu()
+def _refuse_tpu() -> None:
+    """Raise :class:`PallasUnsupported` on a TPU backend."""
+    if jax.default_backend() == "tpu":
+        raise PallasUnsupported(
+            "the jax-pallas executor cannot run on a TPU: its kernels "
+            "compute in float64, which the TPU Pallas compiler refuses "
+            "('64-bit types are not supported'), and their (1, H) blocks "
+            "are not tiled for the TPU; use the default 'jax' executor")
 
 
 # ------------------------------------------------------- dense waterfill
-@functools.partial(jax.jit, static_argnames=("iters", "interpret"))
-def _dense_call(capacity, floors, ceilings, weights, active, *, iters,
-                interpret):
+@functools.partial(jax.jit, static_argnames=("iters",))
+def _dense_call(capacity, floors, ceilings, weights, active, *, iters):
     return kernel.waterfill_call(capacity, floors, ceilings, weights,
-                                 active, iters=iters, interpret=interpret)
+                                 active, iters=iters, interpret=True)
 
 
 def pallas_waterfill_dense(capacity, floors, ceilings, weights,
                            iters: int = 200, active=None):
     """Pallas twin of ``waterfill_dense_math`` (same shape contract:
     ``capacity (..., H)``, slot columns ``(..., H, J)``)."""
+    _refuse_tpu()
     fl = jnp.asarray(floors)
     ce = jnp.asarray(ceilings)
     w = jnp.asarray(weights)
@@ -70,22 +73,20 @@ def pallas_waterfill_dense(capacity, floors, ceilings, weights,
     cap = jnp.broadcast_to(jnp.asarray(capacity), lead + (h,))
     out = _dense_call(cap.reshape((-1, h)), fl.reshape((-1, h, j)),
                       ce.reshape((-1, h, j)), w.reshape((-1, h, j)),
-                      act.reshape((-1, h, j)), iters=iters,
-                      interpret=interpret_mode())
+                      act.reshape((-1, h, j)), iters=iters)
     return out.reshape(fl.shape)
 
 
 # ----------------------------------------------------- fused balance loop
-@functools.partial(jax.jit,
-                   static_argnames=("iters", "params", "interpret"))
+@functools.partial(jax.jit, static_argnames=("iters", "params"))
 def _balance_loop(hosts, caps, fl, ce, w, act, cpu_reserved, budget,
-                  enabled, *, iters, params, interpret):
+                  enabled, *, iters, params):
     on = hosts.on
     n_on = jnp.sum(on, axis=-1)
     peak_managed = core_kernels.peak_managed_capacity(jnp, hosts)
     managed = core_kernels.managed_capacity(jnp, hosts, caps)
     alloc = kernel.waterfill_call(managed, fl, ce, w, act, iters=iters,
-                                  interpret=interpret)
+                                  interpret=True)
     ents = jnp.sum(alloc, axis=-1)
     ns = jnp.where(managed > 0.0, ents / jnp.maximum(managed, 1e-300), 0.0)
     done0 = ~enabled | (n_on < 2)
@@ -99,7 +100,7 @@ def _balance_loop(hosts, caps, fl, ce, w, act, cpu_reserved, budget,
         out = kernel.balance_round_call(
             hosts, (fl, ce, w, act), cpu_reserved, budget, n_on,
             peak_managed, (caps, managed, ents, ns, done, did),
-            iters=iters, params=params, interpret=interpret)
+            iters=iters, params=params, interpret=True)
         return (*out, rounds + 1)
 
     state = (caps, managed, ents, ns, done0, did0, 0)
@@ -116,6 +117,7 @@ def pallas_balance_caps(hosts, caps, dense, cpu_reserved, budget, enabled,
     ``DenseCols`` bundle describing the same entitlement problem as the
     caller's ``ents_at`` closure (which this driver replaces).
     """
+    _refuse_tpu()
     caps = jnp.asarray(caps)
     s, h = caps.shape
     fl = jnp.asarray(dense.floors)
@@ -132,16 +134,15 @@ def pallas_balance_caps(hosts, caps, dense, cpu_reserved, budget, enabled,
         act = jnp.pad(act, pad)
     return _balance_loop(hosts, caps, fl, ce, w, act, cpu_reserved,
                          budget, enabled, iters=int(dense.iters),
-                         params=params, interpret=interpret_mode())
+                         params=params)
 
 
 # ---------------------------------------------------- segmented waterfill
-@functools.partial(jax.jit,
-                   static_argnames=("n", "iters", "jb", "interpret"))
+@functools.partial(jax.jit, static_argnames=("n", "iters", "jb"))
 def _segmented_call(capacity, starts, counts, fl, ce, w, seg_sorted, slot,
-                    perm, *, n, iters, jb, interpret):
+                    perm, *, n, iters, jb):
     dense = kernel.segmented_call(capacity, starts, counts, fl, ce, w,
-                                  iters=iters, jb=jb, interpret=interpret)
+                                  iters=iters, jb=jb, interpret=True)
     alloc_sorted = dense[seg_sorted, slot]
     return jnp.zeros((n,), fl.dtype).at[perm].set(alloc_sorted)
 
@@ -166,8 +167,7 @@ def pallas_waterfill_segmented(capacity, floors, ceilings, weights,
     the original item order.  Per-host math is the dense primitive, so the
     result matches ``waterfill_core`` to reduction-order rounding.
     """
-    from jax.experimental import enable_x64
-
+    _refuse_tpu()
     capacity = np.asarray(capacity, dtype=np.float64)
     floors = np.asarray(floors, dtype=np.float64)
     ceilings = np.asarray(ceilings, dtype=np.float64)
@@ -183,11 +183,11 @@ def pallas_waterfill_segmented(capacity, floors, ceilings, weights,
     jb = _jb_for(int(counts.max()))
     pad = np.zeros(jb, dtype=np.float64)
     slot = np.arange(n, dtype=np.int64) - starts[seg_sorted]
-    with enable_x64():
+    with jax.enable_x64(True):
         return _segmented_call(
             jnp.asarray(capacity), jnp.asarray(starts), jnp.asarray(counts),
             jnp.asarray(np.concatenate([floors[srt], pad])),
             jnp.asarray(np.concatenate([ceilings[srt], pad])),
             jnp.asarray(np.concatenate([weights[srt], pad + 1e-12])),
             jnp.asarray(seg_sorted), jnp.asarray(slot), jnp.asarray(srt),
-            n=n, iters=iters, jb=jb, interpret=interpret_mode())
+            n=n, iters=iters, jb=jb)

@@ -88,8 +88,6 @@ def lax_waterfill_segmented(capacity, floors, ceilings, weights, seg_ids,
     sort by segment, pad rows to the same ``JB``, run the dense primitive
     per host, scatter back.  Bit-identity target for
     ``pallas_waterfill_segmented``."""
-    from jax.experimental import enable_x64
-
     from repro.kernels.powercap.ops import _jb_for
 
     capacity = np.asarray(capacity, dtype=np.float64)
@@ -116,7 +114,7 @@ def lax_waterfill_segmented(capacity, floors, ceilings, weights, seg_ids,
     active[seg_sorted, slot] = True
     # Match the pallas entry point: the eager callers (delivery, tests) may
     # not have x64 on, so the mirror pins it the same way.
-    with enable_x64():
+    with jax.enable_x64(True):
         out_rows = _dense_ref(jnp.asarray(capacity),
                               jnp.asarray(dense_rows(floors)),
                               jnp.asarray(dense_rows(ceilings)),

@@ -7,61 +7,26 @@ Single pod: 16x16 = 256 chips over ("data", "model").
 Multi-pod:  2x16x16 = 512 chips over ("pod", "data", "model"); the "pod"
 axis crosses the DCN, so cross-pod traffic is only data-parallel gradient
 reduction (optionally int8-compressed, repro.optim.compress).
-
-``AxisType`` / explicit axis types only exist in newer jax releases; the
-shim below keeps every mesh constructor (and its callers in tests and
-examples) working on the pinned jax, where meshes are implicitly Auto.
 """
 
 from __future__ import annotations
 
-import inspect
-
 import jax
-
-try:  # jax >= 0.5: first-class mesh axis types
-    from jax.sharding import AxisType
-    HAS_AXIS_TYPES = True
-except ImportError:  # pinned jax: every axis is implicitly Auto
-    class AxisType:  # type: ignore[no-redef]
-        """Stand-in for jax.sharding.AxisType on older jax."""
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-    HAS_AXIS_TYPES = False
-
-_MAKE_MESH_TAKES_AXIS_TYPES = (
-    "axis_types" in inspect.signature(jax.make_mesh).parameters)
-
-
-def make_mesh_compat(shape, axes, *, axis_types=None, devices=None):
-    """``jax.make_mesh`` across jax versions.
-
-    Forwards ``axis_types`` only when the installed jax understands it;
-    older releases treat every axis as Auto, which is exactly what dropping
-    the argument yields.
-    """
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if axis_types is not None and _MAKE_MESH_TAKES_AXIS_TYPES:
-        kwargs["axis_types"] = axis_types
-    return jax.make_mesh(shape, axes, **kwargs)
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes,
-                            axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_pod_mesh(n_pods: int):
     """Elastic-resize meshes: n_pods x 16 x 16 (n_pods=1 drops the axis)."""
     if n_pods == 1:
         return make_production_mesh(multi_pod=False)
-    return make_mesh_compat((n_pods, 16, 16), ("pod", "data", "model"),
-                            axis_types=(AxisType.Auto,) * 3)
+    return jax.make_mesh((n_pods, 16, 16), ("pod", "data", "model"),
+                         axis_types=(AxisType.Auto,) * 3)
 
 
 def make_cells_mesh(n_devices=None):
@@ -79,8 +44,8 @@ def make_cells_mesh(n_devices=None):
     if not 1 <= n <= len(devs):
         raise ValueError(
             f"n_devices={n} outside [1, {len(devs)}] visible devices")
-    return make_mesh_compat((n,), ("cells",), devices=devs[:n],
-                            axis_types=(AxisType.Auto,))
+    return jax.make_mesh((n,), ("cells",), axis_types=(AxisType.Auto,),
+                         devices=devs[:n])
 
 
 def make_host_mesh(shape=None, axes=("data", "model")):
@@ -88,5 +53,4 @@ def make_host_mesh(shape=None, axes=("data", "model")):
     n = len(jax.devices())
     if shape is None:
         shape = (n, 1) if len(axes) == 2 else (n,)
-    return make_mesh_compat(shape, axes,
-                            axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))

@@ -17,7 +17,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.runtime.sharding import current_context, shard
 
@@ -178,8 +177,8 @@ def _moe_ffn_shard_map(params, x, cfg, mesh, rules, expert_ax: str
         args += [params["shared_w_gate"], params["shared_w_up"],
                  params["shared_w_down"]]
     out_specs = (P(bspec, None, None), P())
-    y, aux = shard_map(local_fn, mesh=mesh, in_specs=tuple(in_specs),
-                       out_specs=out_specs, check_rep=False)(*args)
+    y, aux = jax.shard_map(local_fn, mesh=mesh, in_specs=tuple(in_specs),
+                           out_specs=out_specs, check_vma=False)(*args)
     return y, aux
 
 

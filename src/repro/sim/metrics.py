@@ -45,7 +45,6 @@ def fold_timeseries(timeseries: dict, tick_s: float) -> dict:
     order.
     """
     import jax
-    from jax.experimental import enable_x64
 
     @jax.jit
     def fold(ts):
@@ -55,7 +54,7 @@ def fold_timeseries(timeseries: dict, tick_s: float) -> dict:
         return acc
 
     out = {}
-    with enable_x64():
+    with jax.enable_x64(True):
         for k, ts in timeseries.items():
             ts = np.asarray(ts)
             if np.issubdtype(ts.dtype, np.integer):

@@ -40,7 +40,7 @@ import contextlib
 import dataclasses
 import gc
 import os
-import tempfile
+import pathlib
 import time
 import warnings
 from typing import Optional, Sequence
@@ -433,35 +433,31 @@ def _grid_balancer(specs: Sequence[SweepSpec]):
     return None
 
 
-_CACHE_STATE: dict = {"enabled": False, "path": None}
+#: Default home of jax's persistent compilation cache: ``.jax_cache/`` at
+#: the checkout root (``src/repro/sim/sweep.py`` -> three levels up).  It
+#: is resolved from this file, not the working directory, so every process
+#: started from the same checkout finds the same entries.
+CHECKOUT_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
-def enable_compilation_cache(path: Optional[str] = None) -> Optional[str]:
-    """Best-effort enable of jax's persistent compilation cache.
+def enable_compilation_cache() -> str:
+    """Turn on jax's persistent compilation cache; returns its directory.
 
-    Re-invoking the same grid shapes previously paid the full XLA compile
-    every process (the rules grid alone costs ~14 s); with the cache on,
-    a warm re-invocation only pays trace + executable load.  The directory
-    is ``REPRO_JAX_CACHE_DIR`` when set (set it to the empty string to
-    disable), else a per-user directory under the system temp dir.
-    Returns the cache path, or ``None`` when disabled/unsupported.
+    Re-invoking the same grid shapes otherwise pays the full XLA compile
+    in every process; with the cache warm, a re-invocation only pays trace
+    + executable load.  When ``JAX_COMPILATION_CACHE_DIR`` is set, jax
+    already reads that directory and nothing here overrides it; otherwise
+    the cache is :data:`CHECKOUT_CACHE_DIR`.  Call it before the first
+    compile: jax fixes the directory when it first consults the cache.
     """
-    if _CACHE_STATE["enabled"]:
-        return _CACHE_STATE["path"]
-    env = os.environ.get("REPRO_JAX_CACHE_DIR")
-    if env == "":
-        return None
     import jax
-    path = path or env or os.path.join(
-        tempfile.gettempdir(), f"repro-jax-cache-{os.getuid()}")
-    try:
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(CHECKOUT_CACHE_DIR)
         jax.config.update("jax_compilation_cache_dir", path)
-        # Sweep programs are small but slow to build: cache everything.
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:                        # older jax without the knobs
-        return None
-    _CACHE_STATE.update(enabled=True, path=path)
+    # Sweep programs are small but slow to build: cache everything.
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return path
 
 

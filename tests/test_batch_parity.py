@@ -277,7 +277,7 @@ def test_batch_rejects_spec_less_traces():
 
 
 def test_jax_waterfill_matches_numpy():
-    from jax.experimental import enable_x64
+    import jax
 
     from repro.drs.entitlement import batched_waterfill, jax_batched_waterfill
     rng = np.random.RandomState(7)
@@ -294,7 +294,7 @@ def test_jax_waterfill_matches_numpy():
     floors, ceils, weights, seg = map(
         np.concatenate, (floors, ceils, weights, seg))
     ref = batched_waterfill(caps, floors, ceils, weights, seg, n_segs)
-    with enable_x64():
+    with jax.enable_x64(True):
         got = np.asarray(jax_batched_waterfill(caps, floors, ceils, weights,
                                                seg, n_segs))
     np.testing.assert_allclose(got, ref, rtol=1e-9, atol=1e-9)

@@ -26,8 +26,8 @@ seed sweep plus hypothesis-driven generation when hypothesis is available.
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core import kernels
 from repro.core.budget_tree import BudgetTree
@@ -368,7 +368,7 @@ def test_tree_kernels_numpy_jax_parity(seed):
     ref_proj = kernels.tree_project_caps(np, tc, on, caps, floors)
     ref_scope = kernels.tree_evac_scope(np, tc, on, caps, victim)
 
-    with enable_x64():
+    with jax.enable_x64(True):
         tcj = kernels.TreeCols(jnp.asarray(tc.anc), jnp.asarray(tc.limit),
                                jnp.asarray(tc.depth))
         onj, capsj = jnp.asarray(on), jnp.asarray(caps)

@@ -88,15 +88,14 @@ def test_shard_map_scaled_by_mesh():
     import os
     if len(jax.devices()) < 1:
         return
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
+    from jax.sharding import AxisType, PartitionSpec as P
 
-    from repro.launch.mesh import AxisType, make_mesh_compat
-    mesh = make_mesh_compat((1,), ("m",), axis_types=(AxisType.Auto,))
+    mesh = jax.make_mesh((1,), ("m",), axis_types=(AxisType.Auto,))
 
     def f(x):
-        return shard_map(lambda v: v @ v, mesh=mesh, in_specs=P(None, None),
-                         out_specs=P(None, None), check_rep=False)(x)
+        return jax.shard_map(lambda v: v @ v, mesh=mesh,
+                             in_specs=P(None, None), out_specs=P(None, None),
+                             check_vma=False)(x)
     x = jax.ShapeDtypeStruct((8, 8), jnp.float32)
     c = cost_of(f, x)
     assert c["flops"] == 2 * 8 * 8 * 8 * mesh.size

@@ -37,8 +37,8 @@ hypothesis-driven generation when hypothesis is available.
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro import backend as backend_mod
 from repro.backend import NUMPY
@@ -70,7 +70,7 @@ def run_waterfill(executor, capacity, floors, ceils, weights, active):
             return waterfill_dense(np, NUMPY.fori, capacity, floors, ceils,
                                    weights, active=active)
     be = backend_mod.jax_backend()
-    with enable_x64(), backend_mod.executor_scope(executor):
+    with jax.enable_x64(True), backend_mod.executor_scope(executor):
         out = waterfill_dense(jnp, be.fori, jnp.asarray(capacity),
                               jnp.asarray(floors), jnp.asarray(ceils),
                               jnp.asarray(weights),
@@ -97,7 +97,7 @@ def run_balance(executor, problem):
                 enabled, params)
         return np.asarray(caps), np.asarray(did)
     be = backend_mod.jax_backend()
-    with enable_x64(), backend_mod.executor_scope(executor):
+    with jax.enable_x64(True), backend_mod.executor_scope(executor):
         hosts_j = kernels.HostCols(*(jnp.asarray(c) for c in hosts))
         dense_j = kernels.DenseCols(
             jnp.asarray(dense.floors), jnp.asarray(dense.ceils),

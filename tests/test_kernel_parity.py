@@ -2,11 +2,12 @@
 
 The contract under test (`repro.kernels.powercap`): off-TPU the Pallas
 kernels run in interpret mode, where they execute the same float64 op
-sequence as the lax executor and must be **bit-identical** to it -- caps,
-entitlements, and did-anything flags, across random (reservation, limit,
+sequence as the lax executor and must be **bit-identical** to it --
+entitlements and did-anything flags -- across random (reservation, limit,
 shares, demand, budget) tuples and every degenerate regime (zero-demand
 hosts, all-reserved budgets, single-VM hosts, empty hosts, budget below
-the reserved floor).  The NumPy executor differs from the JAX planes only
+the reserved floor).  BalancePowerCap caps are held to a few ULPs instead
+(see :data:`BALANCE_CAPS_MAXULP`).  The NumPy executor differs from the JAX planes only
 by reduction order, so it is compared at ~1 ulp-per-reduction tolerance
 (1e-9 relative), not bitwise.
 
@@ -26,7 +27,6 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro import backend as backend_mod
 from repro.backend import NUMPY
@@ -48,6 +48,15 @@ needs_hypothesis = pytest.mark.skipif(
 SCENARIOS = ("plain", "zero_demand", "all_reserved", "single_vm",
              "empty_host", "budget_below_floor")
 SEEDS = tuple(range(5))
+
+#: Caps from the fused Pallas balance round vs the lax loop.  The same math
+#: runs in both, but interpret mode lowers the kernel body as its own loop
+#: over grid steps with ref loads and stores, a different XLA program from
+#: the lax ``while_loop`` body, and XLA is free to round or contract its
+#: multiply-adds differently.  On JAX 0.9.0 caps of ~100-300 W differ by up
+#: to 4 ULPs (~1.1e-13 W, over 900 seeded problems); the bound leaves 2x
+#: margin for hypothesis's draws.  A broken kernel is off by watts.
+BALANCE_CAPS_MAXULP = 8
 
 
 # ------------------------------------------------------ problem builders
@@ -138,7 +147,7 @@ def segmented_problem(seed: int, scenario: str, n: int = 40,
 # ------------------------------------------------------------ core checks
 def check_dense_parity(seed: int, scenario: str):
     capacity, floors, ceils, weights, active = dense_problem(seed, scenario)
-    with enable_x64():
+    with jax.enable_x64(True):
         got = np.asarray(ops.pallas_waterfill_dense(
             capacity, floors, ceils, weights, active=active))
         want = np.asarray(ref.lax_waterfill_dense(
@@ -155,7 +164,7 @@ def check_balance_parity(seed: int, scenario: str):
     hosts, caps0, dense, cpu_res, budget, enabled = balance_problem(
         seed, scenario)
     params = kernels.BalanceParams()
-    with enable_x64():
+    with jax.enable_x64(True):
         hosts_j = kernels.HostCols(*(jnp.asarray(c) for c in hosts))
         caps_p, did_p = ops.pallas_balance_caps(
             hosts_j, jnp.asarray(caps0), dense, jnp.asarray(cpu_res),
@@ -164,9 +173,8 @@ def check_balance_parity(seed: int, scenario: str):
             hosts, caps0, dense, cpu_res, budget, enabled, params)
         caps_p, did_p = np.asarray(caps_p), np.asarray(did_p)
         caps_l, did_l = np.asarray(caps_l), np.asarray(did_l)
-    assert np.array_equal(caps_p, caps_l), (
-        f"pallas != lax caps (bitwise), max diff "
-        f"{np.abs(caps_p - caps_l).max()}")
+    np.testing.assert_array_max_ulp(caps_p, caps_l,
+                                    maxulp=BALANCE_CAPS_MAXULP)
     assert np.array_equal(did_p, did_l)
 
 
@@ -263,7 +271,7 @@ def test_dense_dispatcher_routes_to_pallas():
     whether the executor dispatches to Pallas or stays on lax."""
     capacity, floors, ceils, weights, active = dense_problem(1, "plain")
     be = backend_mod.jax_backend()
-    with enable_x64():
+    with jax.enable_x64(True):
         args = (jnp.asarray(capacity), jnp.asarray(floors),
                 jnp.asarray(ceils), jnp.asarray(weights))
         act = jnp.asarray(active)
@@ -274,6 +282,27 @@ def test_dense_dispatcher_routes_to_pallas():
             got = np.asarray(waterfill_dense(jnp, be.fori, *args,
                                              active=act))
     assert np.array_equal(got, want)
+
+
+@pytest.mark.parametrize("driver", ("dense", "balance", "segmented"))
+def test_drivers_refuse_tpu(monkeypatch, driver):
+    """On a TPU backend every Pallas driver raises ``PallasUnsupported``
+    before it builds a kernel: a float64 ``pallas_call`` does not lower
+    there."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ops.PallasUnsupported, match="64-bit"):
+        if driver == "dense":
+            capacity, floors, ceils, weights, active = dense_problem(
+                0, "plain")
+            ops.pallas_waterfill_dense(capacity, floors, ceils, weights,
+                                       active=active)
+        elif driver == "balance":
+            hosts, caps0, dense, cpu_res, budget, enabled = balance_problem(
+                0, "plain")
+            ops.pallas_balance_caps(hosts, caps0, dense, cpu_res, budget,
+                                    enabled, kernels.BalanceParams())
+        else:
+            ops.pallas_waterfill_segmented(*segmented_problem(0, "plain"))
 
 
 def test_object_plane_balance_under_pallas_executor():
@@ -324,7 +353,7 @@ def test_padded_slot_leak_regression():
     masked_np = waterfill_dense_math(np, NUMPY.fori, capacity, poison_f,
                                      poison_c, poison_w, active=active)
     assert np.array_equal(masked_np, clean)
-    with enable_x64():
+    with jax.enable_x64(True):
         masked_lax = np.asarray(ref.lax_waterfill_dense(
             capacity, poison_f, poison_c, poison_w, active=active))
         masked_pl = np.asarray(ops.pallas_waterfill_dense(

@@ -28,9 +28,9 @@ SCRIPT = textwrap.dedent("""
                   ('w_up', (e, d, f)), ('w_down', (e, f, d))])}
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, d)) * 0.5
     y_dense, _ = moe._moe_ffn_dense(params, x, cfg)
-    from repro.launch.mesh import AxisType, make_mesh_compat
-    mesh = make_mesh_compat((2, 4), ("data", "model"),
-                            axis_types=(AxisType.Auto,) * 2)
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     with sharding_context(mesh, Rules(batch=("data",), expert=("model",))):
         y_sm, _ = jax.jit(lambda p, xx: moe.moe_ffn(p, xx, cfg))(params, x)
     err = float(jnp.max(jnp.abs(y_dense - y_sm)))

@@ -175,10 +175,14 @@ def test_run_sweep_batched_matches_sequential_rules():
 def test_run_sweep_batched_matches_sequential_timed():
     """Timed-migration families (gated vMotions with copy windows, slot
     limits, and a cluster bandwidth budget) run batched with zero fallback
-    cells and reproduce the sequential sweep's action counts and energy
-    bit for bit.  Payload accumulates per-VM delivery in a different
-    reduction order than the object plane's bincount, so it is compared
-    at tight tolerance rather than exactly."""
+    cells and reproduce the sequential sweep's action counts exactly and
+    its energy to a few ULPs: the arithmetic is the same, but XLA's CPU
+    backend (JAX 0.9.0) contracts the Eq. 1 power and the per-tick
+    ``acc + tick * dt`` into fused multiply-adds, which round once where
+    NumPy rounds twice (1 ULP apart in 4 of these 8 cells).  Payload
+    accumulates per-VM delivery in a different reduction order than the
+    object plane's bincount, so it is compared at tight tolerance rather
+    than exactly."""
     from repro.sim.batch import BatchedSimulator
     from repro.sim.sweep import _build_batch_cells, _grid_balancer
 
@@ -200,7 +204,8 @@ def test_run_sweep_batched_matches_sequential_timed():
             assert (b.cap_changes, b.vmotions, b.power_ons, b.power_offs) \
                 == (a.cap_changes, a.vmotions, a.power_ons,
                     a.power_offs), (name, p)
-            assert b.energy_j == a.energy_j, (name, p)
+            np.testing.assert_array_max_ulp(b.energy_j, a.energy_j,
+                                            maxulp=4)
             np.testing.assert_allclose(b.cpu_payload_mhz_s,
                                        a.cpu_payload_mhz_s, rtol=1e-9)
             migrated |= a.vmotions > 0
