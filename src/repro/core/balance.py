@@ -76,7 +76,7 @@ def balance_power_cap(snapshot: ClusterSnapshot,
                                             floors[None], ceils[None],
                                             weights[None], seg[None])
 
-        caps, did = kernels.balance_caps(
+        caps, did, _ = kernels.balance_caps(
             NUMPY, hosts, av.power_cap[None].copy(), ents_at,
             av.cpu_reserved()[None],
             np.asarray([snapshot.power_budget]),
@@ -140,7 +140,7 @@ def _balance_caps_pallas(snapshot, av, hosts, floors, ceils, weights,
                                     active=dense.active)
             return jnp.sum(alloc, axis=-1)
 
-        caps, did = kernels.balance_caps(
+        caps, did, _ = kernels.balance_caps(
             be, hosts_j, jnp.asarray(av.power_cap[None]), ents_at,
             jnp.asarray(av.cpu_reserved()[None]),
             jnp.asarray([budget]), jnp.asarray([True]),

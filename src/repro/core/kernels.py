@@ -488,8 +488,8 @@ def balance_caps(be, hosts: HostCols, caps, ents_at, cpu_reserved, budget,
         return (*out, rounds + 1)
 
     state = (caps, managed, ents, ns, done0, did0, 0)
-    caps, _, _, _, _, did, _ = be.while_loop(cond, body, state)
-    return caps, did
+    caps, _, _, _, _, did, rounds = be.while_loop(cond, body, state)
+    return caps, did, rounds
 
 
 # -------------------------------------------------- DPM + redistribution

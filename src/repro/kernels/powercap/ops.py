@@ -104,8 +104,8 @@ def _balance_loop(hosts, caps, fl, ce, w, act, cpu_reserved, budget,
         return (*out, rounds + 1)
 
     state = (caps, managed, ents, ns, done0, did0, 0)
-    caps, _, _, _, _, did, _ = jax.lax.while_loop(cond, body, state)
-    return caps, did
+    caps, _, _, _, _, did, rounds = jax.lax.while_loop(cond, body, state)
+    return caps, did, rounds
 
 
 def pallas_balance_caps(hosts, caps, dense, cpu_reserved, budget, enabled,
@@ -125,7 +125,7 @@ def pallas_balance_caps(hosts, caps, dense, cpu_reserved, budget, enabled,
     w = jnp.asarray(dense.weights)
     act = jnp.asarray(dense.active, bool)
     if s == 0 or h == 0:
-        return caps, jnp.zeros(jnp.shape(enabled), bool)
+        return caps, jnp.zeros(jnp.shape(enabled), bool), 0
     if fl.shape[-1] == 0:
         # No slots: pad one inactive slot so the kernel grid is well formed
         # (the masked slot allocates nothing).

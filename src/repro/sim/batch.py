@@ -105,6 +105,7 @@ from repro.drs.entitlement import waterfill_dense
 from repro.drs.snapshot import ClusterSnapshot
 from repro.sim.cluster import SimConfig
 from repro.sim.metrics import Accumulators, fold_timeseries
+from repro.sim.spans import span
 from repro.sim.workloads import DemandTrace, TraceBank
 
 
@@ -210,16 +211,24 @@ class BatchResult:
     final_on: np.ndarray                     # (S, H) power states at the end
     final_occ: np.ndarray                    # (S, H, J) final slot occupancy
     ticks: int
-    wall_s: float = 0.0                      # compile_s + run_s of this call
     n_devices: int = 1                       # cells-mesh size the run used
-    # Timing split (PR 9): AOT compile wall for this batch's program shape
-    # (0.0 on a warm in-process cache), host-side packing wall from
-    # ``_pack``, and dispatch-to-harvest device wall.  ``wall_s`` keeps the
-    # old meaning -- the whole ``run()`` call -- so speedup arithmetic in
-    # the benchmarks is unchanged.
+    # Timing split: AOT compile wall for this batch's program shape (0.0 on
+    # a warm in-process cache), host-side packing wall from ``_pack``, and
+    # dispatch-to-harvest wall (dispatch, device wait, conversions).
     compile_s: float = 0.0
     pack_s: float = 0.0
     run_s: float = 0.0
+    # The run's host spans, ``{name: seconds}`` (``repro.sim.spans``):
+    # ``batch.pack`` and ``batch.compile`` (AOT miss only) when this was
+    # the simulator's first dispatch, then ``batch.dispatch``,
+    # ``batch.wait``, ``batch.fetch`` and ``batch.check``.
+    spans: dict = dataclasses.field(default_factory=dict)
+    # In-scan counters: ``balance_trips``, BalancePowerCap loop trips summed
+    # over the scan's manager invocations (the loop runs until every cell
+    # of a device's shard is done; the largest over shards), and
+    # ``drs_invocations``, the DRS schedule's invocations (``_drs_schedule``;
+    # in the churn regime a deferred invocation can add calls).
+    counters: dict = dataclasses.field(default_factory=dict)
     # ``keep_timeseries=True`` only: field -> (T, S) per-tick rates (floats)
     # and per-tick action counts (ints); ``None`` on the reduced path.
     timeseries: Optional[dict] = None
@@ -300,20 +309,29 @@ def _build_program(static: _StaticSpec):
     FIELDS = ("cpu_payload_mhz_s", "cpu_demand_mhz_s",
               "mem_payload_mb_s", "mem_demand_mb_s", "energy_j")
 
+    def scope(name):
+        """Named scope of one manager phase: every op traced inside carries
+        ``repro/<name>`` in its HLO metadata (``op_name``), which a device
+        profile groups by; the innermost ``repro/`` scope names the phase.
+        Metadata only: it changes nothing XLA fuses."""
+        return jax.named_scope(f"repro/{name}")
+
     def make_demands(a):
         finite_period = jnp.isfinite(a["period"])
 
         def demands(t, trace=None):
             tr = a if trace is None else trace
-            fp = (finite_period if trace is None
-                  else jnp.isfinite(tr["period"]))
-            phase = jnp.where(fp, jnp.mod(t, tr["period"]), t)
-            idx = jnp.clip(
-                jnp.sum(tr["bps"] <= phase[..., None], axis=-1) - 1, 0, None)
-            cpu = jnp.take_along_axis(tr["cpu_vals"], idx[..., None],
-                                      axis=-1)[..., 0]
-            mem = jnp.take_along_axis(tr["mem_vals"], idx[..., None],
-                                      axis=-1)[..., 0]
+            with scope("demand"):
+                fp = (finite_period if trace is None
+                      else jnp.isfinite(tr["period"]))
+                phase = jnp.where(fp, jnp.mod(t, tr["period"]), t)
+                idx = jnp.clip(
+                    jnp.sum(tr["bps"] <= phase[..., None], axis=-1) - 1, 0,
+                    None)
+                cpu = jnp.take_along_axis(tr["cpu_vals"], idx[..., None],
+                                          axis=-1)[..., 0]
+                mem = jnp.take_along_axis(tr["mem_vals"], idx[..., None],
+                                          axis=-1)[..., 0]
             return cpu, mem
         return demands
 
@@ -374,68 +392,78 @@ def _build_program(static: _StaticSpec):
 
         def invoke_manager(caps, cpu):
             """Phase 1 (reserved-floor redivvy) + phase 2 (BalancePowerCap),
-            counting cap changes exactly as ``order_cap_changes`` emits."""
-            redivvied = kernels.redivvy_caps(jnp, on, caps, floor_caps)
-            if tcols is not None:
-                # Tree projection inside the CPC branch only, exactly where
-                # the object plane's ``redivvy_power_cap`` applies it.
-                redivvied = kernels.tree_project_caps(jnp, tcols, on,
-                                                      redivvied, floor_caps)
-            caps1 = jnp.where(a["enabled"][:, None], redivvied, caps)
-            changes = kernels.count_cap_changes(jnp, on, caps, caps1)
-            vm_ceils = jnp.where(
-                active, jnp.clip(cpu, a["reservation"], a["limit"]), 0.0)
+            counting cap changes exactly as ``order_cap_changes`` emits;
+            also returns the BalancePowerCap loop's trip count."""
+            with scope("manager/redivvy"):
+                redivvied = kernels.redivvy_caps(jnp, on, caps, floor_caps)
+                if tcols is not None:
+                    # Tree projection inside the CPC branch only, exactly
+                    # where the object plane's ``redivvy_power_cap``
+                    # applies it.
+                    with scope("manager/tree"):
+                        redivvied = kernels.tree_project_caps(
+                            jnp, tcols, on, redivvied, floor_caps)
+                caps1 = jnp.where(a["enabled"][:, None], redivvied, caps)
+                changes = kernels.count_cap_changes(jnp, on, caps, caps1)
+            with scope("manager/balance"):
+                vm_ceils = jnp.where(
+                    active, jnp.clip(cpu, a["reservation"], a["limit"]),
+                    0.0)
 
-            def ents_at(c):
-                managed = kernels.managed_capacity(jnp, hosts, c)
-                alloc = waterfill_dense(jnp, be.fori, managed, vm_floors,
-                                        vm_ceils, weights, wf_iters,
-                                        active=active)
-                return jnp.sum(alloc, axis=-1)
+                def ents_at(c):
+                    managed = kernels.managed_capacity(jnp, hosts, c)
+                    alloc = waterfill_dense(jnp, be.fori, managed,
+                                            vm_floors, vm_ceils, weights,
+                                            wf_iters, active=active)
+                    return jnp.sum(alloc, axis=-1)
 
-            caps2, _ = kernels.balance_caps(
-                be, hosts, caps1, ents_at, a["cpu_res"], a["budget"],
-                a["enabled"], static.balance,
-                dense=kernels.DenseCols(vm_floors, vm_ceils, weights,
-                                        active, wf_iters))
-            if tcols is not None:
-                caps2 = jnp.where(
-                    a["enabled"][:, None],
-                    kernels.tree_project_caps(jnp, tcols, on, caps2,
-                                              floor_caps),
-                    caps2)
-            changes = changes + kernels.count_cap_changes(jnp, on, caps1,
-                                                          caps2)
-            return caps2, changes.astype(jnp.int32)
+                caps2, _, trips = kernels.balance_caps(
+                    be, hosts, caps1, ents_at, a["cpu_res"], a["budget"],
+                    a["enabled"], static.balance,
+                    dense=kernels.DenseCols(vm_floors, vm_ceils, weights,
+                                            active, wf_iters))
+                if tcols is not None:
+                    with scope("manager/tree"):
+                        caps2 = jnp.where(
+                            a["enabled"][:, None],
+                            kernels.tree_project_caps(jnp, tcols, on, caps2,
+                                                      floor_caps),
+                            caps2)
+                changes = changes + kernels.count_cap_changes(jnp, on, caps1,
+                                                              caps2)
+            return (caps2, changes.astype(jnp.int32),
+                    jnp.asarray(trips, jnp.int32))
 
         def step(carry, x):
             if tcols is None:
                 (caps, acc, win, tag_pay, tag_dem, n_changes,
-                 max_total) = carry
+                 max_total, trips) = carry
             else:
                 (caps, acc, win, tag_pay, tag_dem, n_changes, max_total,
-                 over_tree) = carry
+                 trips, over_tree) = carry
             t, is_drs, in_win = x
             cpu, mem = demands(t)
-            caps, changes = jax.lax.cond(
+            caps, changes, n_trips = jax.lax.cond(
                 is_drs,
                 lambda c: invoke_manager(c, cpu),
-                lambda c: (c, jnp.zeros(S, dtype=jnp.int32)),
+                lambda c: (c, jnp.zeros(S, dtype=jnp.int32), jnp.int32(0)),
                 caps)
-            tick, tp, td, _ = deliver(hosts, caps, on, active, weights,
-                                      a["reservation"], a["limit"],
-                                      a["tag_masks"], cpu, mem)
-            acc = {k: acc[k] + tick[k] * dt for k in acc}
-            win = {k: win[k] + jnp.where(in_win, tick[k], 0.0) * dt
-                   for k in win}
-            carry = (caps, acc, win, tag_pay + tp * dt, tag_dem + td * dt,
-                     n_changes + changes,
-                     jnp.maximum(max_total, jnp.sum(caps * on, axis=-1)))
-            if tcols is not None:
-                carry = carry + (jnp.maximum(
-                    over_tree,
-                    jnp.max(kernels.tree_node_sums(jnp, tcols, on, caps)
-                            - tcols.limit, axis=-1)),)
+            with scope("deliver"):
+                tick, tp, td, _ = deliver(hosts, caps, on, active, weights,
+                                          a["reservation"], a["limit"],
+                                          a["tag_masks"], cpu, mem)
+                acc = {k: acc[k] + tick[k] * dt for k in acc}
+                win = {k: win[k] + jnp.where(in_win, tick[k], 0.0) * dt
+                       for k in win}
+                carry = (caps, acc, win, tag_pay + tp * dt,
+                         tag_dem + td * dt, n_changes + changes,
+                         jnp.maximum(max_total, jnp.sum(caps * on, axis=-1)),
+                         trips + n_trips)
+                if tcols is not None:
+                    carry = carry + (jnp.maximum(
+                        over_tree,
+                        jnp.max(kernels.tree_node_sums(jnp, tcols, on, caps)
+                                - tcols.limit, axis=-1)),)
             if not static.keep_timeseries:
                 return carry, None
             zc = jnp.zeros(S, dtype=jnp.int32)
@@ -446,12 +474,13 @@ def _build_program(static: _StaticSpec):
         init = (a["caps0"], dict(zeros), dict(zeros),
                 jnp.zeros((S, static.n_tags)), jnp.zeros((S, static.n_tags)),
                 jnp.zeros(S, dtype=jnp.int32),
-                jnp.sum(a["caps0"] * a["on"], axis=-1))
+                jnp.sum(a["caps0"] * a["on"], axis=-1), jnp.int32(0))
         if tcols is not None:
             init = init + (jnp.full(S, -jnp.inf),)
         xs = (a["ts"], a["drs_mask"], a["win_mask"])
         final, ys = jax.lax.scan(step, init, xs)
-        (caps, acc, win, tag_pay, tag_dem, n_changes, max_total) = final[:7]
+        (caps, acc, win, tag_pay, tag_dem, n_changes, max_total,
+         trips) = final[:8]
         zi = jnp.zeros(S, dtype=jnp.int32)
         out = {"acc": acc, "win": win, "tag_payload": tag_pay,
                "tag_demand": tag_dem, "cap_changes": n_changes,
@@ -459,9 +488,10 @@ def _build_program(static: _StaticSpec):
                "max_total_cap": max_total, "over_budget": max_total * 0.0,
                "final_caps": caps, "final_on": a["on"],
                "final_occ": a["occ"],
-               "slot_pressure": jnp.zeros(S, dtype=bool)}
+               "slot_pressure": jnp.zeros(S, dtype=bool),
+               "balance_trips": trips[None]}
         if tcols is not None:
-            out["over_tree"] = final[7]
+            out["over_tree"] = final[8]
         if static.keep_timeseries:
             out["timeseries"] = ys
         return out
@@ -537,180 +567,193 @@ def _build_program(static: _StaticSpec):
             # unreserved pool, paper Fig. 3) for CloudPowerCap cells,
             # managed capacity at the current caps for static policies.
             if static.migration and static.rules.any:
-                act0 = work["occ"] & on[..., None]
-                res_pre = jnp.sum(
-                    jnp.where(act0, work["reservation"], 0.0), axis=-1)
-                floors_pre = kernels.reserved_floor_caps(jnp, hosts,
-                                                         res_pre)
-                spare = jnp.maximum(
-                    a["budget"] - jnp.sum(jnp.where(on, floors_pre, 0.0),
-                                          axis=-1), 0.0)
-                fundable = kernels.managed_capacity(
-                    jnp, hosts,
-                    jnp.minimum(floors_pre + spare[:, None], a["peak"]))
-                cap_view = jnp.where(
-                    a["enabled"][:, None], fundable,
-                    kernels.managed_capacity(jnp, hosts, caps))
-                cap_view = jnp.where(on, cap_view, 0.0)
-                work, corr_moves, n_corr, prs, launch = \
-                    kernels.correct_constraints_slots(
-                        be, hosts, cap_view, work, host_mem_spec,
-                        static.rules, can,
-                        jnp.full((S, max(static.rules.move_bound, 1), 3),
-                                 -1, dtype=jnp.int64),
-                        jnp.zeros(S, dtype=jnp.int64), pads=pads,
-                        limits=static.limits, launch=launch)
-                vmot = vmot + n_corr.astype(jnp.int32)
-                mig_pressure = mig_pressure | prs
-
-            act3 = work["occ"] & on[..., None]
-            res = work["reservation"]
-            lim = work["limit"]
-            cpu_res = jnp.sum(jnp.where(act3, res, 0.0), axis=-1)
+                with scope("migration/correct"):
+                    act0 = work["occ"] & on[..., None]
+                    res_pre = jnp.sum(
+                        jnp.where(act0, work["reservation"], 0.0), axis=-1)
+                    floors_pre = kernels.reserved_floor_caps(jnp, hosts,
+                                                             res_pre)
+                    spare = jnp.maximum(
+                        a["budget"] - jnp.sum(
+                            jnp.where(on, floors_pre, 0.0), axis=-1), 0.0)
+                    fundable = kernels.managed_capacity(
+                        jnp, hosts,
+                        jnp.minimum(floors_pre + spare[:, None], a["peak"]))
+                    cap_view = jnp.where(
+                        a["enabled"][:, None], fundable,
+                        kernels.managed_capacity(jnp, hosts, caps))
+                    cap_view = jnp.where(on, cap_view, 0.0)
+                    work, corr_moves, n_corr, prs, launch = \
+                        kernels.correct_constraints_slots(
+                            be, hosts, cap_view, work, host_mem_spec,
+                            static.rules, can,
+                            jnp.full((S, max(static.rules.move_bound, 1), 3),
+                                     -1, dtype=jnp.int64),
+                            jnp.zeros(S, dtype=jnp.int64), pads=pads,
+                            limits=static.limits, launch=launch)
+                    vmot = vmot + n_corr.astype(jnp.int32)
+                    mig_pressure = mig_pressure | prs
 
             # Phase 1b: reserved-floor redivvy (Powercap Allocation) on
             # the post-correction placements.
-            apply_cpc = can & a["enabled"]
-            floor_caps = kernels.reserved_floor_caps(jnp, hosts, cpu_res)
-            redivvied = kernels.redivvy_caps(jnp, on, caps, floor_caps)
-            if tcols is not None:
-                redivvied = kernels.tree_project_caps(jnp, tcols, on,
-                                                      redivvied, floor_caps)
-            caps1 = jnp.where(apply_cpc[:, None], redivvied, caps)
-            changes = jnp.where(
-                can, kernels.count_cap_changes(jnp, on, caps, caps1), 0)
+            with scope("manager/redivvy"):
+                act3 = work["occ"] & on[..., None]
+                res = work["reservation"]
+                lim = work["limit"]
+                cpu_res = jnp.sum(jnp.where(act3, res, 0.0), axis=-1)
+                apply_cpc = can & a["enabled"]
+                floor_caps = kernels.reserved_floor_caps(jnp, hosts, cpu_res)
+                redivvied = kernels.redivvy_caps(jnp, on, caps, floor_caps)
+                if tcols is not None:
+                    with scope("manager/tree"):
+                        redivvied = kernels.tree_project_caps(
+                            jnp, tcols, on, redivvied, floor_caps)
+                caps1 = jnp.where(apply_cpc[:, None], redivvied, caps)
+                changes = jnp.where(
+                    can, kernels.count_cap_changes(jnp, on, caps, caps1), 0)
 
             # Phase 2: BalancePowerCap.
-            vm_floors = jnp.where(act3, jnp.minimum(res, lim), 0.0)
-            vm_ceils = jnp.where(act3, jnp.clip(work["cpu"], res, lim), 0.0)
+            with scope("manager/balance"):
+                vm_floors = jnp.where(act3, jnp.minimum(res, lim), 0.0)
+                vm_ceils = jnp.where(act3, jnp.clip(work["cpu"], res, lim),
+                                     0.0)
 
-            def ents_at(cc):
-                managed = kernels.managed_capacity(jnp, hosts, cc)
-                alloc = waterfill_dense(jnp, be.fori, managed, vm_floors,
-                                        vm_ceils, work["weights"],
-                                        wf_iters, active=act3)
-                return jnp.sum(alloc, axis=-1)
+                def ents_at(cc):
+                    managed = kernels.managed_capacity(jnp, hosts, cc)
+                    alloc = waterfill_dense(jnp, be.fori, managed, vm_floors,
+                                            vm_ceils, work["weights"],
+                                            wf_iters, active=act3)
+                    return jnp.sum(alloc, axis=-1)
 
-            caps2, _ = kernels.balance_caps(
-                be, hosts, caps1, ents_at, cpu_res, a["budget"], apply_cpc,
-                static.balance,
-                dense=kernels.DenseCols(vm_floors, vm_ceils,
-                                        work["weights"], act3, wf_iters))
-            if tcols is not None:
-                caps2 = jnp.where(
-                    apply_cpc[:, None],
-                    kernels.tree_project_caps(jnp, tcols, on, caps2,
-                                              floor_caps),
-                    caps2)
-            changes = changes + jnp.where(
-                can, kernels.count_cap_changes(jnp, on, caps1, caps2), 0)
+                caps2, _, trips = kernels.balance_caps(
+                    be, hosts, caps1, ents_at, cpu_res, a["budget"],
+                    apply_cpc, static.balance,
+                    dense=kernels.DenseCols(vm_floors, vm_ceils,
+                                            work["weights"], act3, wf_iters))
+                if tcols is not None:
+                    with scope("manager/tree"):
+                        caps2 = jnp.where(
+                            apply_cpc[:, None],
+                            kernels.tree_project_caps(jnp, tcols, on, caps2,
+                                                      floor_caps),
+                            caps2)
+                changes = changes + jnp.where(
+                    can, kernels.count_cap_changes(jnp, on, caps1, caps2), 0)
 
             # Phase 2b: residual imbalance fixed by actual migrations
             # (DRS's hill-climb; runs for every policy, like the object
             # plane's ManagerCore).
             if static.migration and static.balancer.max_moves > 0:
-                work, bal_moves, n_bal, prs, launch = \
-                    kernels.balance_migrations(
-                        be, hosts, caps2, work, host_mem_spec,
-                        static.balancer, static.rules, can & a["bal_on"],
-                        jnp.full((S, static.balancer.max_moves, 3), -1,
-                                 dtype=jnp.int64),
-                        jnp.zeros(S, dtype=jnp.int64), pads=pads,
-                        iters=kernels.MIGRATION_WATERFILL_ITERS,
-                        limits=static.limits, launch=launch)
-                vmot = vmot + n_bal.astype(jnp.int32)
-                mig_pressure = mig_pressure | prs
-                act3 = work["occ"] & on[..., None]
-                res = work["reservation"]
-                lim = work["limit"]
-                cpu_res = jnp.sum(jnp.where(act3, res, 0.0), axis=-1)
+                with scope("migration/balance"):
+                    work, bal_moves, n_bal, prs, launch = \
+                        kernels.balance_migrations(
+                            be, hosts, caps2, work, host_mem_spec,
+                            static.balancer, static.rules, can & a["bal_on"],
+                            jnp.full((S, static.balancer.max_moves, 3), -1,
+                                     dtype=jnp.int64),
+                            jnp.zeros(S, dtype=jnp.int64), pads=pads,
+                            iters=kernels.MIGRATION_WATERFILL_ITERS,
+                            limits=static.limits, launch=launch)
+                    vmot = vmot + n_bal.astype(jnp.int32)
+                    mig_pressure = mig_pressure | prs
+                    act3 = work["occ"] & on[..., None]
+                    res = work["reservation"]
+                    lim = work["limit"]
+                    cpu_res = jnp.sum(jnp.where(act3, res, 0.0), axis=-1)
 
             # Phase 3: DPM triggers + Powercap Redistribution, on the
             # post-migration layout.
-            occ = work["occ"]
-            cpu = work["cpu"]
-            mem = work["mem"]
-            eff_slot = jnp.where(act3, jnp.clip(cpu, res, lim), 0.0)
-            eff_h = host_sum_vm_order(eff_slot, act3, work["vm"])
-            mem_h = host_sum_vm_order(mem, act3, work["vm"])
-            cpu_util, mem_util = kernels.host_utilizations(
-                jnp, hosts, caps2, eff_h, mem_h, host_mem_spec)
-            hot_any = jnp.any(kernels.dpm_hot_mask(
-                jnp, on, cpu_util, mem_util, dpmp.high_util), axis=-1)
-            standby = exists & ~on
-            cand = jnp.argmax(standby, axis=-1)
-            do_dpm = can & a["dpm"]
+            with scope("dpm/trigger"):
+                occ = work["occ"]
+                cpu = work["cpu"]
+                mem = work["mem"]
+                eff_slot = jnp.where(act3, jnp.clip(cpu, res, lim), 0.0)
+                eff_h = host_sum_vm_order(eff_slot, act3, work["vm"])
+                mem_h = host_sum_vm_order(mem, act3, work["vm"])
+                cpu_util, mem_util = kernels.host_utilizations(
+                    jnp, hosts, caps2, eff_h, mem_h, host_mem_spec)
+                hot_any = jnp.any(kernels.dpm_hot_mask(
+                    jnp, on, cpu_util, mem_util, dpmp.high_util), axis=-1)
+                standby = exists & ~on
+                cand = jnp.argmax(standby, axis=-1)
+                do_dpm = can & a["dpm"]
 
             # Power-on: fund the first standby host's cap (decreases execute
             # now; the candidate's cap applies now too -- it only counts
             # toward the budget while pending -- and the host joins when the
             # power-on timer fires).
-            want_on = do_dpm & hot_any & jnp.any(standby, axis=-1)
-            funded, granted = kernels.power_on_funding_caps(
-                be, hosts, caps2, cand, cpu_util, eff_h, cpu_res,
-                a["budget"], dpmp.high_util, tree=tcols)
-            cand_cols = kernels.HostCols(
-                *(gather_host(col, cand)[..., None]
-                  for col in (jnp.ones_like(on), a["idle"], a["peak"],
-                              a["cap_peak"], a["hyp"])))
-            feasible = kernels.managed_capacity(
-                jnp, cand_cols, granted[..., None])[..., 0] > 0.0
-            do_on = want_on & jnp.where(a["enabled"], feasible, True)
-            fund = do_on & a["enabled"]
-            is_cand = h_idx[None, :] == cand[..., None]
-            caps3 = jnp.where(fund[:, None], funded, caps2)
-            changes = changes + jnp.where(
-                fund,
-                kernels.count_cap_changes(jnp, on | is_cand, caps2, funded),
-                0)
-            pon_idx = jnp.where(do_on, cand, c["pon_idx"])
-            pon_end = jnp.where(do_on, t + static.power_on_latency_s,
-                                c["pon_end"])
+            with scope("dpm/funding"):
+                want_on = do_dpm & hot_any & jnp.any(standby, axis=-1)
+                funded, granted = kernels.power_on_funding_caps(
+                    be, hosts, caps2, cand, cpu_util, eff_h, cpu_res,
+                    a["budget"], dpmp.high_util, tree=tcols)
+                cand_cols = kernels.HostCols(
+                    *(gather_host(col, cand)[..., None]
+                      for col in (jnp.ones_like(on), a["idle"], a["peak"],
+                                  a["cap_peak"], a["hyp"])))
+                feasible = kernels.managed_capacity(
+                    jnp, cand_cols, granted[..., None])[..., 0] > 0.0
+                do_on = want_on & jnp.where(a["enabled"], feasible, True)
+                fund = do_on & a["enabled"]
+                is_cand = h_idx[None, :] == cand[..., None]
+                caps3 = jnp.where(fund[:, None], funded, caps2)
+                changes = changes + jnp.where(
+                    fund,
+                    kernels.count_cap_changes(jnp, on | is_cand, caps2,
+                                              funded),
+                    0)
+                pon_idx = jnp.where(do_on, cand, c["pon_idx"])
+                pon_end = jnp.where(do_on, t + static.power_on_latency_s,
+                                    c["pon_end"])
 
             # Power-off: sustained cluster-wide low utilization, stability
             # window elapsed, and a complete evacuation plan.
-            n_on = jnp.sum(on, axis=-1)
-            all_low = kernels.dpm_all_low(jnp, on, cpu_util, mem_util,
-                                          dpmp.low_util)
-            ls = jnp.where(jnp.isnan(c["low_since"]), t, c["low_since"])
-            oldest = jnp.maximum(
-                jnp.max(jnp.where(on, ls, -jnp.inf), axis=-1),
-                c["last_cfg"])
-            window_ok = (t - oldest) >= dpmp.stable_window_s
-            maybe_off = (do_dpm & ~hot_any & (n_on > 1) & all_low
-                         & window_ok)
-            victim = jnp.argmin(jnp.where(on, cpu_util, jnp.inf), axis=-1)
-            evac_scope = None
-            if tcols is not None:
-                evac_scope = kernels.tree_evac_scope(jnp, tcols, on, caps2,
-                                                     victim)
-            ok, order, dests, n_evac, pressure = kernels.plan_evacuation(
-                be, hosts, caps2, victim, occ, eff_slot, mem,
-                res, work["migratable"], host_mem_spec,
-                dpmp.target_util, allowed=work.get("allowed"),
-                anti=work.get("anti"), scope=evac_scope)
-            do_off = maybe_off & ok
-            work = _apply_remap(work, do_off, victim, order, dests)
-            vmot = vmot + jnp.where(do_off, n_evac, 0).astype(jnp.int32)
+            with scope("dpm/trigger"):
+                n_on = jnp.sum(on, axis=-1)
+                all_low = kernels.dpm_all_low(jnp, on, cpu_util, mem_util,
+                                              dpmp.low_util)
+                ls = jnp.where(jnp.isnan(c["low_since"]), t, c["low_since"])
+                oldest = jnp.maximum(
+                    jnp.max(jnp.where(on, ls, -jnp.inf), axis=-1),
+                    c["last_cfg"])
+                window_ok = (t - oldest) >= dpmp.stable_window_s
+                maybe_off = (do_dpm & ~hot_any & (n_on > 1) & all_low
+                             & window_ok)
+                victim = jnp.argmin(jnp.where(on, cpu_util, jnp.inf),
+                                    axis=-1)
+            with scope("dpm/evacuation"):
+                evac_scope = None
+                if tcols is not None:
+                    evac_scope = kernels.tree_evac_scope(jnp, tcols, on,
+                                                         caps2, victim)
+                ok, order, dests, n_evac, pressure = kernels.plan_evacuation(
+                    be, hosts, caps2, victim, occ, eff_slot, mem,
+                    res, work["migratable"], host_mem_spec,
+                    dpmp.target_util, allowed=work.get("allowed"),
+                    anti=work.get("anti"), scope=evac_scope)
+                do_off = maybe_off & ok
+                work = _apply_remap(work, do_off, victim, order, dests)
+                vmot = vmot + jnp.where(do_off, n_evac, 0).astype(jnp.int32)
 
-            reabsorbed = kernels.power_off_reabsorb_caps(
-                jnp, hosts, caps2, victim, a["budget"], tree=tcols)
-            # The deferred actions touch exactly the hosts whose cap
-            # change clears the emission threshold (order_cap_changes).
-            changed = on & (jnp.abs(reabsorbed - caps2)
-                            > kernels.CAP_CHANGE_EPS)
-            off_cpc = do_off & a["enabled"]
-            pend_caps = jnp.where(
-                do_off[:, None],
-                jnp.where(off_cpc[:, None], reabsorbed, caps3),
-                c["pend_caps"])
-            pend_mask = jnp.where(do_off[:, None],
-                                  off_cpc[:, None] & changed,
-                                  c["pend_mask"])
-            pend_cnt = jnp.where(off_cpc, jnp.sum(changed, axis=-1),
-                                 0).astype(jnp.int32)
-            pend_cnt = jnp.where(do_off, pend_cnt, c["pend_cnt"])
-            poff_idx = jnp.where(do_off, victim, c["poff_idx"])
+            with scope("dpm/reabsorb"):
+                reabsorbed = kernels.power_off_reabsorb_caps(
+                    jnp, hosts, caps2, victim, a["budget"], tree=tcols)
+                # The deferred actions touch exactly the hosts whose cap
+                # change clears the emission threshold (order_cap_changes).
+                changed = on & (jnp.abs(reabsorbed - caps2)
+                                > kernels.CAP_CHANGE_EPS)
+                off_cpc = do_off & a["enabled"]
+                pend_caps = jnp.where(
+                    do_off[:, None],
+                    jnp.where(off_cpc[:, None], reabsorbed, caps3),
+                    c["pend_caps"])
+                pend_mask = jnp.where(do_off[:, None],
+                                      off_cpc[:, None] & changed,
+                                      c["pend_mask"])
+                pend_cnt = jnp.where(off_cpc, jnp.sum(changed, axis=-1),
+                                     0).astype(jnp.int32)
+                pend_cnt = jnp.where(do_off, pend_cnt, c["pend_cnt"])
+                poff_idx = jnp.where(do_off, victim, c["poff_idx"])
             if static.timed:
                 # ---- Timed regime: the what-if layout above only shaped
                 # *decisions*.  The carry keeps the pre-invocation slots;
@@ -731,64 +774,65 @@ def _build_program(static: _StaticSpec):
                 # charge follows the VM's *current* host while earlier
                 # chain legs are still in flight (``vm.host_id`` in the
                 # object plane).
-                k_idx = jnp.arange(M)
-                scratch = {"occ": c["slots"]["occ"], "mem": mem_pre,
-                           "idx": jnp.full((S, H, J), -1, dtype=jnp.int64)}
-                spads = {"occ": False, "mem": 0.0, "idx": -1}
-                tb = (scratch, c["mig_src"], c["mig_j"], c["mig_dst"],
-                      c["mig_end"], c["mig_prev"],
-                      jnp.zeros(S, dtype=jnp.int64),     # append cursor
-                      jnp.full(S, -jnp.inf))             # FIFO running max
+                with scope("vmotion/launch"):
+                    k_idx = jnp.arange(M)
+                    scratch = {"occ": c["slots"]["occ"], "mem": mem_pre,
+                               "idx": jnp.full((S, H, J), -1, dtype=jnp.int64)}
+                    spads = {"occ": False, "mem": 0.0, "idx": -1}
+                    tb = (scratch, c["mig_src"], c["mig_j"], c["mig_dst"],
+                          c["mig_end"], c["mig_prev"],
+                          jnp.zeros(S, dtype=jnp.int64),     # append cursor
+                          jnp.full(S, -jnp.inf))             # FIFO running max
 
-                def replay(n_k, take, tb):
-                    def body(k, tb):
-                        (sc, msrc, mj, mdst, mend, mprev, cur, eff) = tb
-                        do, src, j, dst = take(k)
-                        si = jnp.clip(src, 0, H - 1)
-                        ji = jnp.clip(j, 0, J - 1)
-                        mem_v = sc["mem"][s_idx, si, ji]
-                        prev_v = sc["idx"][s_idx, si, ji]
-                        dur = jnp.maximum(
-                            jnp.maximum(mem_v, 64.0)
-                            / static.vmotion_rate_mb_s, dt)
-                        eff = jnp.where(do, jnp.maximum(eff, t + dur), eff)
-                        at = do[:, None] & (k_idx[None, :] == cur[:, None])
-                        msrc = jnp.where(at, src[:, None], msrc)
-                        mj = jnp.where(at, j[:, None], mj)
-                        mdst = jnp.where(at, dst[:, None], mdst)
-                        mend = jnp.where(at, eff[:, None], mend)
-                        mprev = jnp.where(at, prev_v[:, None], mprev)
-                        sc = dict(sc, idx=sc["idx"].at[s_idx, si, ji].set(
-                            jnp.where(do, cur, prev_v)))
-                        sc, _ = kernels.move_slot(jnp, sc, do, src, j, dst,
-                                                  spads)
-                        cur = cur + do.astype(cur.dtype)
-                        return (sc, msrc, mj, mdst, mend, mprev, cur, eff)
-                    return be.fori(n_k, body, tb)
+                    def replay(n_k, take, tb):
+                        def body(k, tb):
+                            (sc, msrc, mj, mdst, mend, mprev, cur, eff) = tb
+                            do, src, j, dst = take(k)
+                            si = jnp.clip(src, 0, H - 1)
+                            ji = jnp.clip(j, 0, J - 1)
+                            mem_v = sc["mem"][s_idx, si, ji]
+                            prev_v = sc["idx"][s_idx, si, ji]
+                            dur = jnp.maximum(
+                                jnp.maximum(mem_v, 64.0)
+                                / static.vmotion_rate_mb_s, dt)
+                            eff = jnp.where(do, jnp.maximum(eff, t + dur), eff)
+                            at = do[:, None] & (k_idx[None, :] == cur[:, None])
+                            msrc = jnp.where(at, src[:, None], msrc)
+                            mj = jnp.where(at, j[:, None], mj)
+                            mdst = jnp.where(at, dst[:, None], mdst)
+                            mend = jnp.where(at, eff[:, None], mend)
+                            mprev = jnp.where(at, prev_v[:, None], mprev)
+                            sc = dict(sc, idx=sc["idx"].at[s_idx, si, ji].set(
+                                jnp.where(do, cur, prev_v)))
+                            sc, _ = kernels.move_slot(jnp, sc, do, src, j, dst,
+                                                      spads)
+                            cur = cur + do.astype(cur.dtype)
+                            return (sc, msrc, mj, mdst, mend, mprev, cur, eff)
+                        return be.fori(n_k, body, tb)
 
-                if corr_moves is not None:
-                    tb = replay(corr_moves.shape[1], lambda k: (
-                        k < n_corr, corr_moves[:, k, 0],
-                        corr_moves[:, k, 1], corr_moves[:, k, 2]), tb)
-                if bal_moves is not None:
-                    tb = replay(bal_moves.shape[1], lambda k: (
-                        k < n_bal, bal_moves[:, k, 0],
-                        bal_moves[:, k, 1], bal_moves[:, k, 2]), tb)
-                tb = replay(J, lambda k: (
-                    do_off & (dests[:, k] >= 0), victim, order[:, k],
-                    dests[:, k]), tb)
-                _, mig_src, mig_j, mig_dst, mig_end, mig_prev, _, _ = tb
+                    if corr_moves is not None:
+                        tb = replay(corr_moves.shape[1], lambda k: (
+                            k < n_corr, corr_moves[:, k, 0],
+                            corr_moves[:, k, 1], corr_moves[:, k, 2]), tb)
+                    if bal_moves is not None:
+                        tb = replay(bal_moves.shape[1], lambda k: (
+                            k < n_bal, bal_moves[:, k, 0],
+                            bal_moves[:, k, 1], bal_moves[:, k, 2]), tb)
+                    tb = replay(J, lambda k: (
+                        do_off & (dests[:, k] >= 0), victim, order[:, k],
+                        dests[:, k]), tb)
+                    _, mig_src, mig_j, mig_dst, mig_end, mig_prev, _, _ = tb
 
-                # A power-off waits for its evacuation entries to commit
-                # (its prerequisite edges); evacuations are appended last
-                # and ends are FIFO-monotone, so "last evacuation done"
-                # is exactly "table drained".  No evacuees => the timer
-                # starts now, even with manager moves still in flight.
-                wait = do_off & (n_evac > 0)
-                poff_end = jnp.where(do_off & ~wait,
-                                     t + static.power_off_latency_s,
-                                     c["poff_end"])
-                poff_wait = jnp.where(do_off, wait, c["poff_wait"])
+                    # A power-off waits for its evacuation entries to commit
+                    # (its prerequisite edges); evacuations are appended last
+                    # and ends are FIFO-monotone, so "last evacuation done"
+                    # is exactly "table drained".  No evacuees => the timer
+                    # starts now, even with manager moves still in flight.
+                    wait = do_off & (n_evac > 0)
+                    poff_end = jnp.where(do_off & ~wait,
+                                         t + static.power_off_latency_s,
+                                         c["poff_end"])
+                    poff_wait = jnp.where(do_off, wait, c["poff_wait"])
             else:
                 poff_end = jnp.where(do_off, t + static.power_off_latency_s,
                                      c["poff_end"])
@@ -801,6 +845,8 @@ def _build_program(static: _StaticSpec):
                      pend_caps=pend_caps, pend_mask=pend_mask,
                      pend_cnt=pend_cnt,
                      n_changes=c["n_changes"] + changes.astype(jnp.int32),
+                     balance_trips=(c["balance_trips"]
+                                    + jnp.asarray(trips, jnp.int32)),
                      # Timed cells count vMotions at commit time (the
                      # object plane counts at completion); all launches
                      # eventually commit -- transfers are oblivious to
@@ -840,74 +886,77 @@ def _build_program(static: _StaticSpec):
             prev_counts = {k: c[k] for k in ("n_changes", "vmotions",
                                              "power_ons", "power_offs")}
 
-            # 1. Scripted host lifecycle events.  A returning host boots
-            # with at most the unallocated budget as its cap (the manager
-            # may have reabsorbed its watts while it was away); a grant
-            # held by a host whose power-on is still in flight counts as
-            # allocated, like the budget invariant counts it.
-            on, last_cfg, ev_done = c["on"], c["last_cfg"], c["ev_done"]
-            caps = c["caps"]
-            pend_grant = jnp.where(
-                c["pon_idx"] >= 0,
-                gather_host(caps, jnp.clip(c["pon_idx"], 0, H - 1)), 0.0)
-            for e in range(static.n_events):
-                due = ~ev_done[:, e] & (a["ev_t"][:, e] <= t)
-                eh = a["ev_host"][:, e]
-                target = a["ev_on"][:, e]
-                cur = gather_host(on, eh)
-                onehot = h_idx[None, :] == eh[..., None]
-                boot = due & target & ~cur
-                pool = jnp.maximum(
-                    a["budget"] - jnp.sum(caps * on, axis=-1) - pend_grant,
-                    0.0)
-                caps = jnp.where(
-                    boot[:, None] & onehot,
-                    jnp.minimum(caps, pool[:, None]), caps)
-                if tcols is not None:
-                    # The returning host's cap must also fit its ancestor
-                    # headroom, with the pending power-on grant counted as
-                    # allocated (Simulator._apply_power_events).
-                    pend_on = ((c["pon_idx"] >= 0)[:, None]
-                               & (h_idx[None, :] == c["pon_idx"][:, None]))
-                    head = kernels.tree_headroom(jnp, tcols, on | pend_on,
-                                                 caps)
-                    anc_b = kernels.tree_anc_at(jnp, tcols, eh)
-                    room = jnp.min(jnp.where(anc_b, head, jnp.inf), axis=-1)
+            with scope("lifecycle"):
+                # 1. Scripted host lifecycle events.  A returning host boots
+                # with at most the unallocated budget as its cap (the manager
+                # may have reabsorbed its watts while it was away); a grant
+                # held by a host whose power-on is still in flight counts as
+                # allocated, like the budget invariant counts it.
+                on, last_cfg, ev_done = c["on"], c["last_cfg"], c["ev_done"]
+                caps = c["caps"]
+                pend_grant = jnp.where(
+                    c["pon_idx"] >= 0,
+                    gather_host(caps, jnp.clip(c["pon_idx"], 0, H - 1)), 0.0)
+                for e in range(static.n_events):
+                    due = ~ev_done[:, e] & (a["ev_t"][:, e] <= t)
+                    eh = a["ev_host"][:, e]
+                    target = a["ev_on"][:, e]
+                    cur = gather_host(on, eh)
+                    onehot = h_idx[None, :] == eh[..., None]
+                    boot = due & target & ~cur
+                    pool = jnp.maximum(
+                        a["budget"] - jnp.sum(caps * on, axis=-1) - pend_grant,
+                        0.0)
                     caps = jnp.where(
                         boot[:, None] & onehot,
-                        jnp.minimum(caps,
-                                    jnp.maximum(room, 0.0)[:, None]), caps)
-                on = jnp.where((due & target)[:, None] & onehot, True, on)
-                on = jnp.where((due & ~target)[:, None] & onehot, False, on)
-                last_cfg = jnp.where(due & (cur != target), t, last_cfg)
-                ev_done = ev_done.at[:, e].set(ev_done[:, e] | due)
+                        jnp.minimum(caps, pool[:, None]), caps)
+                    if tcols is not None:
+                        # The returning host's cap must also fit its ancestor
+                        # headroom, with the pending power-on grant counted as
+                        # allocated (Simulator._apply_power_events).
+                        pend_on = ((c["pon_idx"] >= 0)[:, None]
+                                   & (h_idx[None, :] == c["pon_idx"][:, None]))
+                        head = kernels.tree_headroom(jnp, tcols, on | pend_on,
+                                                     caps)
+                        anc_b = kernels.tree_anc_at(jnp, tcols, eh)
+                        room = jnp.min(jnp.where(anc_b, head, jnp.inf),
+                                       axis=-1)
+                        caps = jnp.where(
+                            boot[:, None] & onehot,
+                            jnp.minimum(caps,
+                                        jnp.maximum(room, 0.0)[:, None]), caps)
+                    on = jnp.where((due & target)[:, None] & onehot, True, on)
+                    on = jnp.where((due & ~target)[:, None] & onehot, False,
+                                   on)
+                    last_cfg = jnp.where(due & (cur != target), t, last_cfg)
+                    ev_done = ev_done.at[:, e].set(ev_done[:, e] | due)
 
-            # 2. Pending power-on/off timers come due.
-            pon_fire = (c["pon_idx"] >= 0) & (t >= c["pon_end"])
-            on = on | (pon_fire[:, None]
-                       & (h_idx[None, :] == c["pon_idx"][..., None]))
-            poff_fire = (c["poff_idx"] >= 0) & (t >= c["poff_end"])
-            if static.timed:
-                # A power-off waiting on its evacuation holds a stale
-                # ``poff_end``; its timer starts when the table drains.
-                poff_fire = poff_fire & ~c["poff_wait"]
-            on = on & ~(poff_fire[:, None]
-                        & (h_idx[None, :] == c["poff_idx"][..., None]))
-            # Apply only the hosts the deferred cap *actions* set (the
-            # emitted-change mask), not the whole decision-time column: a
-            # host a scripted event booted during the pending window had
-            # no action and keeps its boot cap.
-            caps = jnp.where(poff_fire[:, None] & c["pend_mask"],
-                             c["pend_caps"], caps)
-            last_cfg = jnp.where(pon_fire | poff_fire, t, last_cfg)
-            c = dict(
-                c, on=on, caps=caps, last_cfg=last_cfg, ev_done=ev_done,
-                n_changes=c["n_changes"]
-                + jnp.where(poff_fire, c["pend_cnt"], 0),
-                power_ons=c["power_ons"] + pon_fire.astype(jnp.int32),
-                power_offs=c["power_offs"] + poff_fire.astype(jnp.int32),
-                pon_idx=jnp.where(pon_fire, -1, c["pon_idx"]),
-                poff_idx=jnp.where(poff_fire, -1, c["poff_idx"]))
+                # 2. Pending power-on/off timers come due.
+                pon_fire = (c["pon_idx"] >= 0) & (t >= c["pon_end"])
+                on = on | (pon_fire[:, None]
+                           & (h_idx[None, :] == c["pon_idx"][..., None]))
+                poff_fire = (c["poff_idx"] >= 0) & (t >= c["poff_end"])
+                if static.timed:
+                    # A power-off waiting on its evacuation holds a stale
+                    # ``poff_end``; its timer starts when the table drains.
+                    poff_fire = poff_fire & ~c["poff_wait"]
+                on = on & ~(poff_fire[:, None]
+                            & (h_idx[None, :] == c["poff_idx"][..., None]))
+                # Apply only the hosts the deferred cap *actions* set (the
+                # emitted-change mask), not the whole decision-time column: a
+                # host a scripted event booted during the pending window had
+                # no action and keeps its boot cap.
+                caps = jnp.where(poff_fire[:, None] & c["pend_mask"],
+                                 c["pend_caps"], caps)
+                last_cfg = jnp.where(pon_fire | poff_fire, t, last_cfg)
+                c = dict(
+                    c, on=on, caps=caps, last_cfg=last_cfg, ev_done=ev_done,
+                    n_changes=c["n_changes"]
+                    + jnp.where(poff_fire, c["pend_cnt"], 0),
+                    power_ons=c["power_ons"] + pon_fire.astype(jnp.int32),
+                    power_offs=c["power_offs"] + poff_fire.astype(jnp.int32),
+                    pon_idx=jnp.where(pon_fire, -1, c["pon_idx"]),
+                    poff_idx=jnp.where(poff_fire, -1, c["poff_idx"]))
 
             # 2b. In-flight migrations commit FIFO (timed regime): each
             # due table entry replays its recorded ``move_slot`` against
@@ -917,35 +966,36 @@ def _build_program(static: _StaticSpec):
             # land on a host that failed or powered off mid-copy, exactly
             # like the object plane's ``move_vm``).
             if static.timed:
-                def commit(cc):
-                    def body(k, st):
-                        slots, msrc, nmig = st
-                        src = cc["mig_src"][:, k]
-                        due = (src >= 0) & (cc["mig_end"][:, k] <= t)
-                        slots, _ = kernels.move_slot(
-                            jnp, slots, due, src, cc["mig_j"][:, k],
-                            cc["mig_dst"][:, k], pads)
-                        msrc = msrc.at[:, k].set(jnp.where(due, -1, src))
-                        return slots, msrc, nmig + due.astype(jnp.int32)
-                    slots, msrc, nmig = be.fori(
-                        M, body, (cc["slots"], cc["mig_src"],
-                                  jnp.zeros(S, dtype=jnp.int32)))
-                    return dict(cc, slots=slots, mig_src=msrc,
-                                vmotions=cc["vmotions"] + nmig)
+                with scope("vmotion/commit"):
+                    def commit(cc):
+                        def body(k, st):
+                            slots, msrc, nmig = st
+                            src = cc["mig_src"][:, k]
+                            due = (src >= 0) & (cc["mig_end"][:, k] <= t)
+                            slots, _ = kernels.move_slot(
+                                jnp, slots, due, src, cc["mig_j"][:, k],
+                                cc["mig_dst"][:, k], pads)
+                            msrc = msrc.at[:, k].set(jnp.where(due, -1, src))
+                            return slots, msrc, nmig + due.astype(jnp.int32)
+                        slots, msrc, nmig = be.fori(
+                            M, body, (cc["slots"], cc["mig_src"],
+                                      jnp.zeros(S, dtype=jnp.int32)))
+                        return dict(cc, slots=slots, mig_src=msrc,
+                                    vmotions=cc["vmotions"] + nmig)
 
-                c = jax.lax.cond(
-                    jnp.any((c["mig_src"] >= 0) & (c["mig_end"] <= t)),
-                    commit, lambda cc: cc, c)
-                # Evacuation entries committed => the deferred power-off's
-                # prerequisites are met: start its latency timer now
-                # (object plane: ``_complete_actions`` then
-                # ``_start_actions`` in the same tick).
-                drained = ~jnp.any(c["mig_src"] >= 0, axis=-1)
-                start_off = c["poff_wait"] & drained
-                c = dict(c, poff_wait=c["poff_wait"] & ~start_off,
-                         poff_end=jnp.where(
-                             start_off, t + static.power_off_latency_s,
-                             c["poff_end"]))
+                    c = jax.lax.cond(
+                        jnp.any((c["mig_src"] >= 0) & (c["mig_end"] <= t)),
+                        commit, lambda cc: cc, c)
+                    # Evacuation entries committed => the deferred power-off's
+                    # prerequisites are met: start its latency timer now
+                    # (object plane: ``_complete_actions`` then
+                    # ``_start_actions`` in the same tick).
+                    drained = ~jnp.any(c["mig_src"] >= 0, axis=-1)
+                    start_off = c["poff_wait"] & drained
+                    c = dict(c, poff_wait=c["poff_wait"] & ~start_off,
+                             poff_end=jnp.where(
+                                 start_off, t + static.power_off_latency_s,
+                                 c["poff_end"]))
 
             # 3. Manager invocation on the carried DRS schedule; deferred
             # per cell while its power actions are in flight.
@@ -970,81 +1020,87 @@ def _build_program(static: _StaticSpec):
             active = c["slots"]["occ"] & on[..., None]
             overhead = None
             if static.timed:
-                # Endpoint vMotion overhead from the (post-invocation)
-                # in-flight table: each entry charges its destination and
-                # its VM's *current* host.  For chained launches that is
-                # the earliest uncommitted leg's source -- commits drain
-                # FIFO, so the committed prefix never interleaves and a
-                # bounded predecessor walk finds it.
-                act_m = c["mig_src"] >= 0
-                eff_src, prev = c["mig_src"], c["mig_prev"]
+                with scope("vmotion/overhead"):
+                    # Endpoint vMotion overhead from the (post-invocation)
+                    # in-flight table: each entry charges its destination and
+                    # its VM's *current* host.  For chained launches that is
+                    # the earliest uncommitted leg's source -- commits drain
+                    # FIFO, so the committed prefix never interleaves and a
+                    # bounded predecessor walk finds it.
+                    act_m = c["mig_src"] >= 0
+                    eff_src, prev = c["mig_src"], c["mig_prev"]
 
-                def hop(_, st):
-                    eff_src, prev = st
-                    pc = jnp.clip(prev, 0, M - 1)
-                    live = (prev >= 0) & jnp.take_along_axis(act_m, pc,
-                                                             axis=-1)
-                    eff_src = jnp.where(
-                        live,
-                        jnp.take_along_axis(c["mig_src"], pc, axis=-1),
-                        eff_src)
-                    prev = jnp.where(
-                        live,
-                        jnp.take_along_axis(c["mig_prev"], pc, axis=-1),
-                        jnp.full_like(prev, -1))
-                    return eff_src, prev
+                    def hop(_, st):
+                        eff_src, prev = st
+                        pc = jnp.clip(prev, 0, M - 1)
+                        live = (prev >= 0) & jnp.take_along_axis(act_m, pc,
+                                                                 axis=-1)
+                        eff_src = jnp.where(
+                            live,
+                            jnp.take_along_axis(c["mig_src"], pc, axis=-1),
+                            eff_src)
+                        prev = jnp.where(
+                            live,
+                            jnp.take_along_axis(c["mig_prev"], pc, axis=-1),
+                            jnp.full_like(prev, -1))
+                        return eff_src, prev
 
-                eff_src, _ = be.fori(M, hop, (eff_src, prev))
-                ep = ((eff_src[..., None] == h_idx[None, None, :])
-                      | (c["mig_dst"][..., None] == h_idx[None, None, :]))
-                overhead = static.vmotion_overhead_mhz * jnp.sum(
-                    act_m[..., None] & ep, axis=1)
-            tick, tp, td, mem_dem_h = deliver(
-                hosts, caps, on, active, c["slots"]["weights"],
-                c["slots"]["reservation"], c["slots"]["limit"],
-                c["slots"]["tag_masks"], cpu, mem, overhead=overhead)
+                    eff_src, _ = be.fori(M, hop, (eff_src, prev))
+                    ep = ((eff_src[..., None] == h_idx[None, None, :])
+                          | (c["mig_dst"][..., None] == h_idx[None, None, :]))
+                    overhead = static.vmotion_overhead_mhz * jnp.sum(
+                        act_m[..., None] & ep, axis=1)
+            with scope("deliver"):
+                tick, tp, td, mem_dem_h = deliver(
+                    hosts, caps, on, active, c["slots"]["weights"],
+                    c["slots"]["reservation"], c["slots"]["limit"],
+                    c["slots"]["tag_masks"], cpu, mem, overhead=overhead)
 
-            # Budget invariant: powered-on caps plus the cap of a host whose
-            # power-on is pending (it holds its grant while joining).
-            pend_cap = jnp.where(
-                c["pon_idx"] >= 0,
-                gather_host(caps, jnp.clip(c["pon_idx"], 0, H - 1)), 0.0)
-            total = jnp.sum(caps * on, axis=-1) + pend_cap
-            if tcols is not None:
-                # Per-node invariant with the pending power-on target
-                # counted as allocated (its grant is its already-set cap).
-                tree_mask = on | ((c["pon_idx"] >= 0)[:, None]
-                                  & (h_idx[None, :] == c["pon_idx"][:, None]))
-                node_over = (kernels.tree_node_sums(jnp, tcols, tree_mask,
-                                                    caps)
-                             - tcols.limit)
-                over_tree = jnp.maximum(c["over_tree"],
-                                        jnp.max(node_over, axis=-1))
+                # Budget invariant: powered-on caps plus the cap of a host
+                # whose power-on is pending (it holds its grant while
+                # joining).
+                pend_cap = jnp.where(
+                    c["pon_idx"] >= 0,
+                    gather_host(caps, jnp.clip(c["pon_idx"], 0, H - 1)), 0.0)
+                total = jnp.sum(caps * on, axis=-1) + pend_cap
+                if tcols is not None:
+                    # Per-node invariant with the pending power-on target
+                    # counted as allocated (its grant is its already-set cap).
+                    tree_mask = on | (
+                        (c["pon_idx"] >= 0)[:, None]
+                        & (h_idx[None, :] == c["pon_idx"][:, None]))
+                    node_over = (kernels.tree_node_sums(jnp, tcols, tree_mask,
+                                                        caps)
+                                 - tcols.limit)
+                    over_tree = jnp.maximum(c["over_tree"],
+                                            jnp.max(node_over, axis=-1))
 
-            # 6. DPM low-watermark tracking at delivered capacity, through
-            # the same utilization kernel the invocation's triggers use.
-            eff = jnp.clip(cpu, c["slots"]["reservation"],
-                           c["slots"]["limit"])
-            eff_h = jnp.sum(jnp.where(active, eff, 0.0), axis=-1)
-            cpu_util, mem_util = kernels.host_utilizations(
-                jnp, hosts, caps, eff_h, mem_dem_h, host_mem_spec)
-            low = on & (cpu_util < dpmp.low_util) & (
-                mem_util < dpmp.low_util)
-            entering = low & jnp.isnan(c["low_since"])
-            low_since = jnp.where(entering, t, c["low_since"])
-            low_since = jnp.where(on & ~low, jnp.nan, low_since)
+            with scope("dpm/trigger"):
+                # 6. DPM low-watermark tracking at delivered capacity, through
+                # the same utilization kernel the invocation's triggers use.
+                eff = jnp.clip(cpu, c["slots"]["reservation"],
+                               c["slots"]["limit"])
+                eff_h = jnp.sum(jnp.where(active, eff, 0.0), axis=-1)
+                cpu_util, mem_util = kernels.host_utilizations(
+                    jnp, hosts, caps, eff_h, mem_dem_h, host_mem_spec)
+                low = on & (cpu_util < dpmp.low_util) & (
+                    mem_util < dpmp.low_util)
+                entering = low & jnp.isnan(c["low_since"])
+                low_since = jnp.where(entering, t, c["low_since"])
+                low_since = jnp.where(on & ~low, jnp.nan, low_since)
 
-            c = dict(
-                c, low_since=low_since,
-                acc={k: c["acc"][k] + tick[k] * dt for k in c["acc"]},
-                win={k: c["win"][k] + jnp.where(in_win, tick[k], 0.0) * dt
-                     for k in c["win"]},
-                tag_pay=c["tag_pay"] + tp * dt,
-                tag_dem=c["tag_dem"] + td * dt,
-                over_budget=jnp.maximum(c["over_budget"],
-                                        total - a["budget"]))
-            if tcols is not None:
-                c["over_tree"] = over_tree
+            with scope("deliver"):
+                c = dict(
+                    c, low_since=low_since,
+                    acc={k: c["acc"][k] + tick[k] * dt for k in c["acc"]},
+                    win={k: c["win"][k] + jnp.where(in_win, tick[k], 0.0) * dt
+                         for k in c["win"]},
+                    tag_pay=c["tag_pay"] + tp * dt,
+                    tag_dem=c["tag_dem"] + td * dt,
+                    over_budget=jnp.maximum(c["over_budget"],
+                                            total - a["budget"]))
+                if tcols is not None:
+                    c["over_tree"] = over_tree
             if not static.keep_timeseries:
                 return c, None
             return c, dict(
@@ -1076,6 +1132,7 @@ def _build_program(static: _StaticSpec):
             "power_ons": zi, "power_offs": zi,
             "over_budget": jnp.full(S, -jnp.inf),
             "slot_pressure": jnp.zeros(S, dtype=bool),
+            "balance_trips": jnp.int32(0),
         }
         if tcols is not None:
             init["over_tree"] = jnp.full(S, -jnp.inf)
@@ -1097,14 +1154,21 @@ def _build_program(static: _StaticSpec):
                "over_budget": c["over_budget"],
                "final_caps": c["caps"], "final_on": c["on"],
                "final_occ": c["slots"]["occ"],
-               "slot_pressure": c["slot_pressure"]}
+               "slot_pressure": c["slot_pressure"],
+               "balance_trips": c["balance_trips"][None]}
         if tcols is not None:
             out["over_tree"] = c["over_tree"]
         if static.keep_timeseries:
             out["timeseries"] = ys
         return out
 
-    program = build_churn if static.churn else build_static
+    build = build_churn if static.churn else build_static
+
+    def program(a):
+        # Ops outside every phase (the tick loop's carry copies, the DRS
+        # conditional, set-up before the scan) fall under ``repro/scan``.
+        with scope("scan"):
+            return build(a)
     return program
 
 
@@ -1123,7 +1187,7 @@ def _out_specs(static: _StaticSpec, P):
         "acc", "win", "tag_payload", "tag_demand", "cap_changes",
         "vmotions", "power_ons", "power_offs", "max_total_cap",
         "over_budget", "final_caps", "final_on", "final_occ",
-        "slot_pressure")}
+        "slot_pressure", "balance_trips")}
     if static.n_tree_nodes:
         specs["over_tree"] = P("cells")
     if static.keep_timeseries:
@@ -1226,6 +1290,10 @@ class BatchedSimulator:
     pre-slack slot axis) up to at least the given sizes -- the sweep
     layer's pad-bucketing uses them to pin every grid in a pow2 shape
     class to the same compiled program.
+
+    ``span_ids`` (e.g. ``{"sweep": 3, "bucket": 0}``) tag the simulator's
+    host spans in a profiler trace (``repro.sim.spans``); the spans' times
+    land in each :class:`BatchResult`'s ``spans``.
     """
 
     def __init__(self, cells: Sequence[BatchCell],
@@ -1237,10 +1305,15 @@ class BatchedSimulator:
                  n_devices: Optional[int] = None,
                  pad_hosts: int = 0,
                  pad_slots: int = 0,
-                 keep_timeseries: bool = False):
+                 keep_timeseries: bool = False,
+                 span_ids: Optional[dict] = None):
         if not cells:
             raise ValueError("no cells")
         self.cells = list(cells)
+        self._span_ids = dict(span_ids or {})
+        # Spans not yet reported by a dispatch: the pack below, and a
+        # compile on an AOT miss.  The next ``run_async`` takes them over.
+        self._spans: dict = {}
         self.config = cells[0].config
         self._n_devices = n_devices
         self._keep_timeseries = bool(keep_timeseries)
@@ -1268,8 +1341,11 @@ class BatchedSimulator:
         # instead of atomic remaps.
         self._timed = (self._mig_ref is not None
                        and not self._mig_ref.instant_migrations)
-        self._pack(balance or kernels.BalanceParams(),
-                   dpm or kernels.DPMParams(), waterfill_iters, slot_slack)
+        with span("batch.pack", self._spans, **self._span_ids):
+            self._pack(balance or kernels.BalanceParams(),
+                       dpm or kernels.DPMParams(), waterfill_iters,
+                       slot_slack)
+        self.pack_s = self._spans["batch.pack"]
 
     # ---------------------------------------------------------- validation
     @staticmethod
@@ -1391,7 +1467,6 @@ class BatchedSimulator:
     def _pack(self, balance: kernels.BalanceParams,
               dpm: kernels.DPMParams, waterfill_iters: int,
               slot_slack: float) -> None:
-        t_pack0 = time.perf_counter()
         cells = self.cells
         S = len(cells)
         H = max(max(len(c.snapshot.hosts) for c in cells), self._pad_hosts)
@@ -1625,11 +1700,6 @@ class BatchedSimulator:
             n_tree_nodes=n_tree)
         self._ticks = T
         self._prepared = None
-        # Compile wall not yet reported by a dispatch: the sweep pipeline
-        # compiles on a worker before ``run_async``, whose own ``compile``
-        # call then finds the executable cached.
-        self._unreported_compile_s = 0.0
-        self.pack_s = time.perf_counter() - t_pack0
 
     # ------------------------------------------------------------- running
     def _prepare(self):
@@ -1661,33 +1731,29 @@ class BatchedSimulator:
         self._prepared = (static, n_dev, a, sig)
         return self._prepared
 
-    def compile(self) -> float:
+    def compile(self) -> None:
         """Ensure this batch's program shape is AOT-compiled.
 
         ``jit(...).lower(a).compile()`` lands the executable in
         :data:`_AOT_EXECUTABLES` keyed by the shape signature (the XLA
         persistent compile cache still backs the expensive part across
-        processes).  Returns the wall seconds this call spent compiling,
-        0.0 on a warm cache; the next dispatch reports them as its
-        ``compile_s``.  Thread-safe: the sweep pipeline fires one
-        ``compile`` per shape class concurrently from its worker pool
-        (``jax.enable_x64`` is thread-local; the executor pin is re-read from
-        the static spec)."""
+        processes).  A miss records a ``batch.compile`` span, which the
+        next dispatch reports as its ``compile_s``; a hit records nothing.
+        Thread-safe: the sweep pipeline fires one ``compile`` per shape
+        class concurrently from its worker pool (``jax.enable_x64`` is
+        thread-local; the executor pin is re-read from the static spec)."""
         static, n_dev, a, sig = self._prepare()
         with _AOT_LOCK:
             if sig in _AOT_EXECUTABLES:
-                return 0.0
+                return
         import jax
-        t0 = time.perf_counter()
-        with jax.enable_x64(True), \
+        with span("batch.compile", self._spans, **self._span_ids), \
+                jax.enable_x64(True), \
                 backend_mod.executor_scope(self._static.executor), \
                 _quiet_donation():
             exe = _compiled_program(static, n_dev).lower(a).compile()
         with _AOT_LOCK:
             _AOT_EXECUTABLES[sig] = exe
-        dt = time.perf_counter() - t0
-        self._unreported_compile_s += dt
-        return dt
 
     def run_async(self) -> "PendingBatch":
         """Compile (if not already) and dispatch without blocking: jax
@@ -1696,87 +1762,98 @@ class BatchedSimulator:
         packing) while the device works.  Harvest with
         :meth:`PendingBatch.result`."""
         self.compile()
-        compile_s, self._unreported_compile_s = self._unreported_compile_s, 0.0
+        spans, self._spans = self._spans, {}
         static, n_dev, a, sig = self._prepare()
         import jax
         t0 = time.perf_counter()
-        with jax.enable_x64(True), \
+        with span("batch.dispatch", spans, **self._span_ids), \
+                jax.enable_x64(True), \
                 backend_mod.executor_scope(self._static.executor), \
                 _quiet_donation():
             raw = _AOT_EXECUTABLES[sig](a)
-        return PendingBatch(sim=self, raw=raw, dispatch_t0=t0,
-                            compile_s=compile_s, n_devices=n_dev)
+        return PendingBatch(sim=self, raw=raw, dispatch_t0=t0, spans=spans,
+                            n_devices=n_dev)
 
     def run(self) -> BatchResult:
         return self.run_async().result()
 
-    def _harvest(self, raw, dispatch_t0: float, compile_s: float,
+    def _harvest(self, raw, dispatch_t0: float, spans: dict,
                  n_dev: int) -> BatchResult:
-        """Block on the dispatched outputs, check invariants, and assemble
-        the :class:`BatchResult` (the ``np.asarray`` conversions are the
-        synchronization point)."""
+        """Block on the dispatched outputs, convert them, check invariants,
+        and assemble the :class:`BatchResult`, recording the
+        ``batch.wait``, ``batch.fetch`` and ``batch.check`` spans into
+        ``spans``, the run's record."""
+        import jax
         S = self._static.n_cells
-        out = {}
-        for k, v in raw.items():
-            if k == "timeseries":
-                # Per-tick series are (T, S): the cells axis is axis 1.
-                out[k] = {kk: np.asarray(vv)[:, :S] for kk, vv in v.items()}
-            elif isinstance(v, dict):
-                out[k] = {kk: np.asarray(vv)[:S] for kk, vv in v.items()}
-            else:
-                out[k] = np.asarray(v)[:S]
+        with span("batch.wait", spans, **self._span_ids):
+            jax.block_until_ready(raw)
+        with span("batch.fetch", spans, **self._span_ids):
+            out = {}
+            for k, v in raw.items():
+                if k == "timeseries":
+                    # Per-tick series are (T, S): the cells axis is axis 1.
+                    out[k] = {kk: np.asarray(vv)[:, :S]
+                              for kk, vv in v.items()}
+                elif isinstance(v, dict):
+                    out[k] = {kk: np.asarray(vv)[:S] for kk, vv in v.items()}
+                else:
+                    out[k] = np.asarray(v)[:S]
         run_s = time.perf_counter() - dispatch_t0
+        with span("batch.check", spans, **self._span_ids):
+            # Post-hoc invariants, checked in one shot for the whole grid.
+            if bool(out["slot_pressure"].any()):
+                bad = [self.cells[i].name
+                       for i in np.nonzero(out["slot_pressure"])[0]]
+                raise RuntimeError(
+                    f"slot capacity bound a migration/evacuation decision in "
+                    f"cells {bad[:5]}: repack with a larger slot_slack")
+            if self._static.churn:
+                over = out["over_budget"]
+            else:
+                over = out["max_total_cap"] - self._arrays["budget"]
+            assert float(over.max()) <= 1e-6, (
+                f"budget violated during execution: worst overshoot "
+                f"{float(over.max()):.3f} W (cell "
+                f"{self.cells[int(over.argmax())].name})")
+            if "over_tree" in out:
+                ot = out["over_tree"]
+                assert float(ot.max()) <= 1e-6, (
+                    f"budget tree violated during execution: worst node over "
+                    f"by {float(ot.max()):.3f} W (cell "
+                    f"{self.cells[int(ot.argmax())].name})")
 
-        # Post-hoc invariants, checked in one shot for the whole grid.
-        if bool(out["slot_pressure"].any()):
-            bad = [self.cells[i].name
-                   for i in np.nonzero(out["slot_pressure"])[0]]
-            raise RuntimeError(
-                f"slot capacity bound a migration/evacuation decision in "
-                f"cells {bad[:5]}: repack with a larger slot_slack")
-        if self._static.churn:
-            over = out["over_budget"]
-        else:
-            over = out["max_total_cap"] - self._arrays["budget"]
-        assert float(over.max()) <= 1e-6, (
-            f"budget violated during execution: worst overshoot "
-            f"{float(over.max()):.3f} W (cell "
-            f"{self.cells[int(over.argmax())].name})")
-        if "over_tree" in out:
-            ot = out["over_tree"]
-            assert float(ot.max()) <= 1e-6, (
-                f"budget tree violated during execution: worst node over by "
-                f"{float(ot.max()):.3f} W (cell "
-                f"{self.cells[int(ot.argmax())].name})")
-
-        acc = out["acc"]
-        return BatchResult(
-            names=[c.name for c in self.cells],
-            cpu_payload_mhz_s=acc["cpu_payload_mhz_s"],
-            cpu_demand_mhz_s=acc["cpu_demand_mhz_s"],
-            mem_payload_mb_s=acc["mem_payload_mb_s"],
-            mem_demand_mb_s=acc["mem_demand_mb_s"],
-            energy_j=acc["energy_j"],
-            cap_changes=out["cap_changes"],
-            vmotions=out["vmotions"],
-            power_ons=out["power_ons"],
-            power_offs=out["power_offs"],
-            tag_names=self._tag_names,
-            tag_payload=out["tag_payload"],
-            tag_demand=out["tag_demand"],
-            window_fields=out["win"],
-            has_window=np.array([c.window is not None for c in self.cells]),
-            final_caps=out["final_caps"],
-            final_on=out["final_on"],
-            final_occ=out["final_occ"],
-            ticks=self._ticks,
-            wall_s=compile_s + run_s,
-            n_devices=n_dev,
-            compile_s=compile_s,
-            pack_s=self.pack_s,
-            run_s=run_s,
-            timeseries=out.get("timeseries"),
-            tick_s=self._static.tick_s)
+            acc = out["acc"]
+            return BatchResult(
+                names=[c.name for c in self.cells],
+                cpu_payload_mhz_s=acc["cpu_payload_mhz_s"],
+                cpu_demand_mhz_s=acc["cpu_demand_mhz_s"],
+                mem_payload_mb_s=acc["mem_payload_mb_s"],
+                mem_demand_mb_s=acc["mem_demand_mb_s"],
+                energy_j=acc["energy_j"],
+                cap_changes=out["cap_changes"],
+                vmotions=out["vmotions"],
+                power_ons=out["power_ons"],
+                power_offs=out["power_offs"],
+                tag_names=self._tag_names,
+                tag_payload=out["tag_payload"],
+                tag_demand=out["tag_demand"],
+                window_fields=out["win"],
+                has_window=np.array([c.window is not None
+                                     for c in self.cells]),
+                final_caps=out["final_caps"],
+                final_on=out["final_on"],
+                final_occ=out["final_occ"],
+                ticks=self._ticks,
+                n_devices=n_dev,
+                compile_s=spans.get("batch.compile", 0.0),
+                pack_s=self.pack_s,
+                run_s=run_s,
+                spans=spans,
+                counters={
+                    "balance_trips": int(out["balance_trips"].max()),
+                    "drs_invocations": int(self._arrays["drs_mask"].sum())},
+                timeseries=out.get("timeseries"),
+                tick_s=self._static.tick_s)
 
 
 @dataclasses.dataclass
@@ -1792,9 +1869,9 @@ class PendingBatch:
     sim: BatchedSimulator
     raw: dict
     dispatch_t0: float
-    compile_s: float
+    spans: dict                  # the run's record (``BatchResult.spans``)
     n_devices: int
 
     def result(self) -> BatchResult:
-        return self.sim._harvest(self.raw, self.dispatch_t0,
-                                 self.compile_s, self.n_devices)
+        return self.sim._harvest(self.raw, self.dispatch_t0, self.spans,
+                                 self.n_devices)

@@ -55,6 +55,7 @@ from repro.drs.snapshot import ClusterSnapshot, Host, VirtualMachine
 from repro.sim.cluster import SimConfig
 from repro.sim.experiments import ENGINES, POLICIES
 from repro.sim import workloads
+from repro.sim.spans import next_sweep_id, span
 
 # A smaller, less efficient host mixed in for heterogeneous sweeps:
 # 8 cores x 2.4 GHz, 64 GB, idle 120 W / peak 240 W.
@@ -462,12 +463,16 @@ def enable_compilation_cache() -> str:
 
 
 #: Per-bucket records from the most recent batched ``run_sweep`` /
-#: ``run_sweep_batched`` call: shape class, cell count, mesh size, and the
+#: ``run_sweep_batched`` call: shape class, cell count, mesh size, the
 #: split timing -- ``compile_s`` (AOT compile wall for never-seen program
 #: shapes, ~0 on a warm in-process or persistent cache), ``pack_s``
-#: (host-side array packing), ``run_s`` (dispatch-to-harvest device wall),
-#: and ``wall_s`` (compile + run, the old whole-call meaning).  Benchmarks
-#: read it to report the cost split per bucket.
+#: (host-side array packing), ``run_s`` (dispatch-to-harvest wall) -- and
+#: the call's ``sweep`` id, the bucket's host ``spans`` (``{name:
+#: seconds}``, ``BatchResult.spans``) and in-scan ``counters``
+#: (``BatchResult.counters``).  Bucket 0's record also carries
+#: ``sweep_spans``, the call-level spans (``sweep``, ``sweep.build``,
+#: ``sweep.partition``, ``sweep.assemble``).  Benchmarks read it to report
+#: the cost split per bucket.
 LAST_BATCH_INFO: list = []
 
 #: Worker threads for the overlapped pipeline: bucket N+1 packs and
@@ -526,7 +531,8 @@ def _cell_results(res, keys) -> dict:
     return out
 
 
-def _run_pipeline(buckets, n_devices: Optional[int] = None,
+def _run_pipeline(buckets, sweep_id: int, sweep_spans: dict,
+                  n_devices: Optional[int] = None,
                   slot_slack: float = 3.0) -> dict:
     """Overlapped execution of prepared buckets; the device never waits on
     the host.
@@ -540,7 +546,8 @@ def _run_pipeline(buckets, n_devices: Optional[int] = None,
     packing.  Results are harvested only at the end (in
     :func:`_harvest_order`), merged into the flat ``{(spec.name, policy):
     result}`` map, and one record per bucket lands in
-    :data:`LAST_BATCH_INFO` in bucket order.
+    :data:`LAST_BATCH_INFO` in bucket order, bucket 0's carrying the
+    call's ``sweep_spans``.
     """
     from concurrent.futures import ThreadPoolExecutor, as_completed
 
@@ -552,7 +559,8 @@ def _run_pipeline(buckets, n_devices: Optional[int] = None,
         hp, jp, cells, _, balancer = buckets[i]
         sim = BatchedSimulator(cells, slot_slack=slot_slack,
                                balancer=balancer, n_devices=n_devices,
-                               pad_hosts=hp, pad_slots=jp)
+                               pad_hosts=hp, pad_slots=jp,
+                               span_ids={"sweep": sweep_id, "bucket": i})
         sim.compile()
         return i, sim
 
@@ -575,28 +583,35 @@ def _run_pipeline(buckets, n_devices: Optional[int] = None,
             "compile_s": res.compile_s,
             "pack_s": res.pack_s,
             "run_s": res.run_s,
-            "wall_s": res.wall_s,
+            "sweep": sweep_id,
+            "spans": res.spans,
+            "counters": res.counters,
         }
-        flat.update(_cell_results(res, keys))
+        with span("sweep.assemble", sweep_spans, sweep=sweep_id):
+            flat.update(_cell_results(res, keys))
+    infos[0]["sweep_spans"] = sweep_spans
     LAST_BATCH_INFO.extend(infos)
     return flat
 
 
-def _run_buckets(cells, keys, n_devices: Optional[int] = None,
+def _run_buckets(cells, keys, sweep_id: int, sweep_spans: dict,
+                 n_devices: Optional[int] = None,
                  slot_slack: float = 3.0) -> dict:
     """Pad-bucket partitioner: group cells into pow2 (H, J) shape classes,
     one compiled program per bucket, each bucket's cells axis sharded over
     the device mesh, all buckets overlapped through the pipeline.  Returns
     the flat {(spec.name, policy): result} map."""
-    by_bucket: dict[tuple[int, int], list] = {}
-    for c, k in zip(cells, keys):
-        by_bucket.setdefault(_bucket_key(c), []).append((c, k))
-    work = []
-    for (hp, jp), pairs in sorted(by_bucket.items()):
-        bspecs = list(dict.fromkeys(k[0] for _, k in pairs))
-        work.append((hp, jp, [c for c, _ in pairs], [k for _, k in pairs],
-                     _grid_balancer(bspecs)))
-    return _run_pipeline(work, n_devices=n_devices, slot_slack=slot_slack)
+    with span("sweep.partition", sweep_spans, sweep=sweep_id):
+        by_bucket: dict[tuple[int, int], list] = {}
+        for c, k in zip(cells, keys):
+            by_bucket.setdefault(_bucket_key(c), []).append((c, k))
+        work = []
+        for (hp, jp), pairs in sorted(by_bucket.items()):
+            bspecs = list(dict.fromkeys(k[0] for _, k in pairs))
+            work.append((hp, jp, [c for c, _ in pairs],
+                         [k for _, k in pairs], _grid_balancer(bspecs)))
+    return _run_pipeline(work, sweep_id, sweep_spans, n_devices=n_devices,
+                         slot_slack=slot_slack)
 
 
 def _same_trace_specs(a: dict, b: dict, vm_ids: Sequence[str]) -> bool:
@@ -711,37 +726,46 @@ def run_sweep(specs: Sequence[SweepSpec],
     ``VectorSimulator``, and the results are merged -- never silently
     freezing the unsupported dimension.  Merged results always follow the
     input ``specs`` x ``policies`` order, whatever the partitioning.
+
+    Each batched call takes a fresh ``sweep`` id and records its host spans
+    (``repro.sim.spans``) into :data:`LAST_BATCH_INFO`.
     """
     if engine == "batch":
         from repro.sim.batch import BatchedSimulator, BatchUnsupported
         LAST_BATCH_INFO.clear()
-        cells, keys = _build_batch_cells(specs, policies)
-        reasons = BatchedSimulator.unsupported_cells(
-            cells, _grid_balancer(specs))
-        if reasons and on_unsupported != "fallback":
-            # Probe the whole grid up front: bucketing could otherwise
-            # mask e.g. a time-grid mismatch by splitting the disagreeing
-            # cells into different buckets.
-            name, why = min(reasons.items())
-            raise BatchUnsupported(f"cell {name!r}: {why}")
-        if reasons:
-            warnings.warn(
-                "batched engine cannot run cells "
-                f"{sorted(reasons)[:5]}{'...' if len(reasons) > 5 else ''} "
-                f"({next(iter(reasons.values()))}); running those on the "
-                "sequential vector engine and batching the rest",
-                RuntimeWarning, stacklevel=2)
-        good = [(c, k) for c, k in zip(cells, keys)
-                if f"{k[0].name}/{k[1]}" not in reasons]
-        flat = (_run_buckets([c for c, _ in good], [k for _, k in good],
-                             n_devices=n_devices)
-                if good else {})
-        out: dict[str, dict[str, SweepCellResult]] = {}
-        for spec in specs:
-            out[spec.name] = {
-                p: flat.get((spec.name, p))
-                or run_cell(spec, p, engine="vector")
-                for p in policies}
+        sweep_id, record = next_sweep_id(), {}
+        with span("sweep", record, sweep=sweep_id):
+            with span("sweep.build", record, sweep=sweep_id):
+                cells, keys = _build_batch_cells(specs, policies)
+            with span("sweep.partition", record, sweep=sweep_id):
+                reasons = BatchedSimulator.unsupported_cells(
+                    cells, _grid_balancer(specs))
+            if reasons and on_unsupported != "fallback":
+                # Probe the whole grid up front: bucketing could otherwise
+                # mask e.g. a time-grid mismatch by splitting the
+                # disagreeing cells into different buckets.
+                name, why = min(reasons.items())
+                raise BatchUnsupported(f"cell {name!r}: {why}")
+            if reasons:
+                warnings.warn(
+                    "batched engine cannot run cells "
+                    f"{sorted(reasons)[:5]}"
+                    f"{'...' if len(reasons) > 5 else ''} "
+                    f"({next(iter(reasons.values()))}); running those on "
+                    "the sequential vector engine and batching the rest",
+                    RuntimeWarning, stacklevel=2)
+            good = [(c, k) for c, k in zip(cells, keys)
+                    if f"{k[0].name}/{k[1]}" not in reasons]
+            flat = (_run_buckets([c for c, _ in good], [k for _, k in good],
+                                 sweep_id, record, n_devices=n_devices)
+                    if good else {})
+            with span("sweep.assemble", record, sweep=sweep_id):
+                out: dict[str, dict[str, SweepCellResult]] = {}
+                for spec in specs:
+                    out[spec.name] = {
+                        p: flat.get((spec.name, p))
+                        or run_cell(spec, p, engine="vector")
+                        for p in policies}
         return out
     out = {}
     for spec in specs:
@@ -771,7 +795,8 @@ def run_sweep_batched(specs: Sequence[SweepSpec],
     cells, keys = _prebuilt or _build_batch_cells(specs, policies)
     LAST_BATCH_INFO.clear()
     flat = _run_pipeline([(0, 0, cells, keys, _grid_balancer(specs))],
-                         n_devices=n_devices, slot_slack=slot_slack)
+                         next_sweep_id(), {}, n_devices=n_devices,
+                         slot_slack=slot_slack)
     out: dict[str, dict[str, SweepCellResult]] = {}
     for spec, p in keys:
         out.setdefault(spec.name, {})[p] = flat[(spec.name, p)]

@@ -92,7 +92,7 @@ def run_balance(executor, problem):
             return np.sum(alloc, axis=-1)
 
         with backend_mod.executor_scope(executor):
-            caps, did = kernels.balance_caps(
+            caps, did, _ = kernels.balance_caps(
                 NUMPY, hosts, caps0.copy(), ents_at, cpu_res, budget,
                 enabled, params)
         return np.asarray(caps), np.asarray(did)
@@ -110,7 +110,7 @@ def run_balance(executor, problem):
                                     active=dense_j.active)
             return jnp.sum(alloc, axis=-1)
 
-        caps, did = kernels.balance_caps(
+        caps, did, _ = kernels.balance_caps(
             be, hosts_j, jnp.asarray(caps0), ents_at, jnp.asarray(cpu_res),
             jnp.asarray(budget), jnp.asarray(enabled), params,
             dense=dense_j)

@@ -166,16 +166,17 @@ def check_balance_parity(seed: int, scenario: str):
     params = kernels.BalanceParams()
     with jax.enable_x64(True):
         hosts_j = kernels.HostCols(*(jnp.asarray(c) for c in hosts))
-        caps_p, did_p = ops.pallas_balance_caps(
+        caps_p, did_p, rounds_p = ops.pallas_balance_caps(
             hosts_j, jnp.asarray(caps0), dense, jnp.asarray(cpu_res),
             jnp.asarray(budget), jnp.asarray(enabled), params)
-        caps_l, did_l = ref.lax_balance_caps(
+        caps_l, did_l, rounds_l = ref.lax_balance_caps(
             hosts, caps0, dense, cpu_res, budget, enabled, params)
         caps_p, did_p = np.asarray(caps_p), np.asarray(did_p)
         caps_l, did_l = np.asarray(caps_l), np.asarray(did_l)
     np.testing.assert_array_max_ulp(caps_p, caps_l,
                                     maxulp=BALANCE_CAPS_MAXULP)
     assert np.array_equal(did_p, did_l)
+    assert int(rounds_p) == int(rounds_l)
 
 
 def check_segmented_parity(seed: int, scenario: str):
