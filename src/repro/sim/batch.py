@@ -293,6 +293,34 @@ _SLOT_PAD = dict(kernels.SLOT_PAD, period=np.inf, cpu_vals=0.0,
                  mem_vals=0.0, tag_masks=False, vm=-1)
 
 
+def trace_demands(tr, t):
+    """(cpu, mem) of every slot's step-function demand trace at time ``t``.
+
+    ``tr`` holds the packed trace columns in ``TraceBank``'s layout:
+    ``period`` (...,) and ``bps``, ``cpu_vals``, ``mem_vals`` (..., K).
+    The segment index is ``TraceBank.eval``'s; the value is selected by
+    ``idx == k`` over the static, short segment axis.  XLA lowers a
+    ``take_along_axis`` there to a serial per-element gather, which took
+    nine tenths of the cap-only scan's time on a TPU v5e; the select
+    chain is elementwise and returns the gather's values bit for bit.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    with jax.named_scope("repro/demand"):
+        phase = jnp.where(jnp.isfinite(tr["period"]),
+                          jnp.mod(t, tr["period"]), t)
+        idx = jnp.clip(
+            jnp.sum(tr["bps"] <= phase[..., None], axis=-1) - 1, 0, None)
+        cpu = tr["cpu_vals"][..., 0]
+        mem = tr["mem_vals"][..., 0]
+        for k in range(1, tr["bps"].shape[-1]):
+            hit = idx == k
+            cpu = jnp.where(hit, tr["cpu_vals"][..., k], cpu)
+            mem = jnp.where(hit, tr["mem_vals"][..., k], mem)
+    return cpu, mem
+
+
 def _build_program(static: _StaticSpec):
     """Build the (untraced) whole-grid program for one per-device shape."""
     import jax
@@ -317,22 +345,8 @@ def _build_program(static: _StaticSpec):
         return jax.named_scope(f"repro/{name}")
 
     def make_demands(a):
-        finite_period = jnp.isfinite(a["period"])
-
         def demands(t, trace=None):
-            tr = a if trace is None else trace
-            with scope("demand"):
-                fp = (finite_period if trace is None
-                      else jnp.isfinite(tr["period"]))
-                phase = jnp.where(fp, jnp.mod(t, tr["period"]), t)
-                idx = jnp.clip(
-                    jnp.sum(tr["bps"] <= phase[..., None], axis=-1) - 1, 0,
-                    None)
-                cpu = jnp.take_along_axis(tr["cpu_vals"], idx[..., None],
-                                          axis=-1)[..., 0]
-                mem = jnp.take_along_axis(tr["mem_vals"], idx[..., None],
-                                          axis=-1)[..., 0]
-            return cpu, mem
+            return trace_demands(a if trace is None else trace, t)
         return demands
 
     def make_deliver(a):

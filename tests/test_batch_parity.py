@@ -298,3 +298,72 @@ def test_jax_waterfill_matches_numpy():
         got = np.asarray(jax_batched_waterfill(caps, floors, ceils, weights,
                                                seg, n_segs))
     np.testing.assert_allclose(got, ref, rtol=1e-9, atol=1e-9)
+
+
+def _step_functions(k, rng, n):
+    """``n`` random step functions of at most ``k`` segments in TraceBank's
+    layout: integer breakpoints (so ``mod`` is exact and phases can land on
+    them), ``inf``-padded past each slot's segment count, every other slot
+    periodic, values with ``-0.0`` and ``inf`` among them."""
+    gaps = rng.randint(1, 5, (n, k)) * 10.0
+    gaps[:, 0] = rng.randint(0, 3, n) * 10.0         # first breakpoint >= 0
+    bps = np.cumsum(gaps, axis=1)
+    n_segs = rng.randint(1, k + 1, n)
+    bps[np.arange(k)[None, :] >= n_segs[:, None]] = np.inf
+    last = bps[np.arange(n), n_segs - 1]
+    period = np.where(np.arange(n) % 2 == 0,
+                      last + rng.randint(1, 4, n) * 10.0, np.inf)
+    cpu, mem = rng.uniform(0.0, 3000.0, (2, n, k))
+    cpu[rng.rand(n, k) < 0.1] = -0.0
+    mem[rng.rand(n, k) < 0.1] = np.inf
+    finite = np.unique(bps[np.isfinite(bps)])
+    laps = np.unique(period[np.isfinite(period)])[:, None] * (1.0, 2.0)
+    ts = np.concatenate([[0.0, 5.0, 1234.5], finite, finite + 0.5,
+                         laps.ravel(), (laps[:, :, None] + finite).ravel()])
+    return period, bps, cpu, mem, np.unique(ts)
+
+
+@pytest.mark.parametrize("k", (1, 2, 3, 4))
+def test_trace_lookup_matches_gather_bit_for_bit(k):
+    """The scan's select over the segment axis returns exactly what a
+    ``take_along_axis`` gather and ``TraceBank.eval`` return, at every
+    phase: on a breakpoint, between, before the first, past a period."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.sim.batch import trace_demands
+
+    shape = (3, 4, 5)                                 # cells, hosts, slots
+    rng = np.random.RandomState(100 + k)
+    period, bps, cpu, mem, ts = _step_functions(k, rng, int(np.prod(shape)))
+    bank = workloads.TraceBank([f"vm{i}" for i in range(len(period))])
+    bank.rows = np.arange(len(period))
+    bank.period, bank.bps, bank.cpu_vals, bank.mem_vals = period, bps, cpu, mem
+
+    def gather(tr, t):
+        phase = jnp.where(jnp.isfinite(tr["period"]),
+                          jnp.mod(t, tr["period"]), t)
+        idx = jnp.clip(jnp.sum(tr["bps"] <= phase[..., None], axis=-1) - 1,
+                       0, None)
+        return tuple(jnp.take_along_axis(tr[c], idx[..., None], axis=-1)
+                     [..., 0] for c in ("cpu_vals", "mem_vals"))
+
+    tr = {"period": period.reshape(shape),
+          "bps": bps.reshape(shape + (k,)),
+          "cpu_vals": cpu.reshape(shape + (k,)),
+          "mem_vals": mem.reshape(shape + (k,))}
+
+    def bits(x):
+        return np.asarray(x, dtype=np.float64).reshape(-1).view(np.uint64)
+
+    with jax.enable_x64(True):
+        tr = {c: jnp.asarray(v) for c, v in tr.items()}
+        select, take = jax.jit(trace_demands), jax.jit(gather)
+        for t in ts:
+            got = select(tr, jnp.float64(t))
+            want = take(tr, jnp.float64(t))
+            rows, b_cpu, b_mem = bank.eval(float(t))
+            assert (rows == np.arange(len(period))).all()
+            for g, w, b in zip(got, want, (b_cpu, b_mem)):
+                assert (bits(g) == bits(w)).all(), t
+                assert (bits(g) == bits(b)).all(), t

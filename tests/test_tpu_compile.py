@@ -15,6 +15,8 @@ a time may load the TPU library, and under pytest-xdist every worker
 imports every test file, so only the worker given this file may touch it.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -69,6 +71,16 @@ def _f64(shape, sharding, dtype=jnp.float64):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
+def _demand_gathers(hlo):
+    """Lines of optimised HLO built for a gather traced in ``repro/demand``.
+
+    The TPU's float64 rewrite renames the ``gather`` instruction's own
+    ``op_name`` to a bare ``gather``; the index packing and the fusion
+    around it keep the traced name, ``.../repro/demand/.../gather``."""
+    return [line for line in hlo.splitlines()
+            if re.search(r'op_name="[^"]*repro/demand/[^"]*gather', line)]
+
+
 def test_waterfill_dense_compiles_for_v5e(one_chip, no_cache):
     be = backend_mod.jax_backend()
 
@@ -105,6 +117,21 @@ def test_balance_caps_compiles_for_v5e(one_chip, no_cache):
              col, _f64((S,), one_chip), _f64((S,), one_chip, jnp.bool_))
 
 
+def test_demand_lookup_has_no_gather_on_v5e(one_chip, no_cache):
+    """The per-tick trace lookup selects over the 3-segment axis: XLA
+    lowers a gather there to a serial per-element loop on the chip."""
+    from repro.sim.batch import trace_demands
+
+    K = 3                          # segments of the spike traces
+    tr = {"period": _f64((S, H, J), one_chip)}
+    tr.update({c: _f64((S, H, J, K), one_chip)
+               for c in ("bps", "cpu_vals", "mem_vals")})
+    hlo = _compile(trace_demands, tr, _f64((), one_chip)).as_text()
+    assert "repro/demand" in hlo
+    assert " gather(" not in hlo
+    assert not _demand_gathers(hlo)
+
+
 def test_cap_only_program_compiles_for_v5e(one_chip, no_cache):
     """The whole cap-only scan of ``run_sweep(engine="batch")`` for the four
     spike x host-mix families at 64 hosts, every policy, one DRS period."""
@@ -130,4 +157,6 @@ def test_cap_only_program_compiles_for_v5e(one_chip, no_cache):
     # The chip holds every packed input, padded to its tiles.
     assert compiled.memory_analysis().argument_size_in_bytes >= sum(
         v.nbytes for v in arrays.values())
-    assert "f64" in compiled.as_text()
+    hlo = compiled.as_text()
+    assert "f64" in hlo
+    assert "repro/demand" in hlo and not _demand_gathers(hlo)
