@@ -652,24 +652,28 @@ def power_off_reabsorb_caps(xp, hosts: HostCols, caps, off_idx, budget,
     return tree_project_caps(xp, tree, on_after, result, caps0)
 
 
-def plan_evacuation(be, hosts: HostCols, caps, victim, occ, eff_slot,
+def plan_evacuation(be, hosts: HostCols, caps, victim, occ, vm, eff_slot,
                     mem_slot, res_slot, migratable, host_mem,
                     target_util: float, allowed=None, anti=None,
                     scope=None):
     """DPM evacuation planning on the dense slot layout ``(S, H, J)``.
 
     Replays ``repro.drs.dpm.run_dpm``'s greedy: the victim's VMs leave in
-    decreasing current-memory order (stable on ties), each to the feasible
-    powered-on host with the strictly lowest post-move utilization (first
-    host on ties), subject to the reservation/memory fit check and the
-    ``target_util`` ceiling on both CPU and memory.  All-or-nothing: a
-    single unplaceable or unmigratable VM cancels the whole evacuation.
+    decreasing current-memory order, ties in ascending global VM index
+    (``vm``, ``(S, H, J)`` int: each slot's VM, as ``run_dpm`` sorts the
+    inventory), each to the feasible powered-on host with the strictly
+    lowest post-move utilization (first host on ties), subject to the
+    reservation/memory fit check and the ``target_util`` ceiling on both
+    CPU and memory.  All-or-nothing: a single unplaceable or unmigratable
+    VM cancels the whole evacuation.
 
-    Returns ``(ok, order, dests, n_evac, slot_pressure)``: ``order`` is the
-    per-cell slot visit order, ``dests[:, k]`` the destination host of the
-    k-th evacuee (-1 when unused), and ``slot_pressure`` flags cells where
-    the ``J`` slot bound excluded an otherwise-feasible destination (the
-    caller must treat those results as invalid -- repack with more slack).
+    Returns ``(ok, order, dests, n_evac, slot_pressure, trips)``:
+    ``order`` is the per-cell slot visit order, ``dests[:, k]`` the
+    destination host of the k-th evacuee (-1 when unused),
+    ``slot_pressure`` flags cells where the ``J`` slot bound excluded an
+    otherwise-feasible destination (the caller must treat those results as
+    invalid -- repack with more slack), and ``trips`` the planner loop's
+    trips: ``J``, whatever the victims hold.
 
     ``allowed`` (``(S, H, J, H)``) and ``anti`` (``(S, H, J, R)``) add rule
     admission to the fit check (the object plane's ``placement.fits``):
@@ -708,7 +712,15 @@ def plan_evacuation(be, hosts: HostCols, caps, victim, occ, eff_slot,
     vic_mig = at_victim(migratable)
     vic_allowed = at_victim(allowed) if allowed is not None else None
     vic_anti = at_victim(anti) if anti is not None else None
-    order = be.argsort(xp.where(vic_occ, -vic_mem, xp.inf), axis=-1)
+    # Visit order: memory descending, ties by global VM index (empty slots
+    # last).  Slot order is not VM order once a first-free placement has
+    # landed a VM on the victim, and the greedy's destinations depend on
+    # the order it visits equal-memory VMs in.
+    by_vm = be.argsort(xp.where(vic_occ, at_victim(vm),
+                                xp.iinfo(vm.dtype).max), axis=-1)
+    by_mem = be.argsort(xp.take_along_axis(
+        xp.where(vic_occ, -vic_mem, xp.inf), by_vm, axis=-1), axis=-1)
+    order = xp.take_along_axis(by_vm, by_mem, axis=-1)
     n_vic = xp.sum(vic_occ, axis=-1)
 
     def order_k(k):
@@ -777,7 +789,7 @@ def plan_evacuation(be, hosts: HostCols, caps, victim, occ, eff_slot,
     st = be.fori(j, body, init)
     ok, dests, pressure = st["ok"], st["dests"], st["pressure"]
     n_evac = xp.where(ok, n_vic, 0)
-    return ok, order, dests, n_evac, pressure
+    return ok, order, dests, n_evac, pressure, j
 
 
 # ------------------------------------------------------- migration layer

@@ -227,7 +227,10 @@ class BatchResult:
     # over the scan's manager invocations (the loop runs until every cell
     # of a device's shard is done; the largest over shards), and
     # ``drs_invocations``, the DRS schedule's invocations (``_drs_schedule``;
-    # in the churn regime a deferred invocation can add calls).
+    # in the churn regime a deferred invocation can add calls).  The churn
+    # regime adds ``evac_trips``, the DPM evacuation planner's loop trips
+    # summed over the scan's manager invocations (largest over shards), and
+    # ``power_offs`` and ``power_ons``, summed over cells.
     counters: dict = dataclasses.field(default_factory=dict)
     # ``keep_timeseries=True`` only: field -> (T, S) per-tick rates (floats)
     # and per-tick action counts (ints); ``None`` on the reduced path.
@@ -740,8 +743,9 @@ def _build_program(static: _StaticSpec):
                 if tcols is not None:
                     evac_scope = kernels.tree_evac_scope(jnp, tcols, on,
                                                          caps2, victim)
-                ok, order, dests, n_evac, pressure = kernels.plan_evacuation(
-                    be, hosts, caps2, victim, occ, eff_slot, mem,
+                (ok, order, dests, n_evac, pressure,
+                 evac_trips) = kernels.plan_evacuation(
+                    be, hosts, caps2, victim, occ, work["vm"], eff_slot, mem,
                     res, work["migratable"], host_mem_spec,
                     dpmp.target_util, allowed=work.get("allowed"),
                     anti=work.get("anti"), scope=evac_scope)
@@ -861,6 +865,7 @@ def _build_program(static: _StaticSpec):
                      n_changes=c["n_changes"] + changes.astype(jnp.int32),
                      balance_trips=(c["balance_trips"]
                                     + jnp.asarray(trips, jnp.int32)),
+                     evac_trips=c["evac_trips"] + jnp.int32(evac_trips),
                      # Timed cells count vMotions at commit time (the
                      # object plane counts at completion); all launches
                      # eventually commit -- transfers are oblivious to
@@ -1147,6 +1152,7 @@ def _build_program(static: _StaticSpec):
             "over_budget": jnp.full(S, -jnp.inf),
             "slot_pressure": jnp.zeros(S, dtype=bool),
             "balance_trips": jnp.int32(0),
+            "evac_trips": jnp.int32(0),
         }
         if tcols is not None:
             init["over_tree"] = jnp.full(S, -jnp.inf)
@@ -1169,7 +1175,8 @@ def _build_program(static: _StaticSpec):
                "final_caps": c["caps"], "final_on": c["on"],
                "final_occ": c["slots"]["occ"],
                "slot_pressure": c["slot_pressure"],
-               "balance_trips": c["balance_trips"][None]}
+               "balance_trips": c["balance_trips"][None],
+               "evac_trips": c["evac_trips"][None]}
         if tcols is not None:
             out["over_tree"] = c["over_tree"]
         if static.keep_timeseries:
@@ -1202,6 +1209,8 @@ def _out_specs(static: _StaticSpec, P):
         "vmotions", "power_ons", "power_offs", "max_total_cap",
         "over_budget", "final_caps", "final_on", "final_occ",
         "slot_pressure", "balance_trips")}
+    if static.churn:
+        specs["evac_trips"] = P("cells")
     if static.n_tree_nodes:
         specs["over_tree"] = P("cells")
     if static.keep_timeseries:
@@ -1836,6 +1845,13 @@ class BatchedSimulator:
                     f"by {float(ot.max()):.3f} W (cell "
                     f"{self.cells[int(ot.argmax())].name})")
 
+            counters = {
+                "balance_trips": int(out["balance_trips"].max()),
+                "drs_invocations": int(self._arrays["drs_mask"].sum())}
+            if self._static.churn:
+                counters.update(evac_trips=int(out["evac_trips"].max()),
+                                power_offs=int(out["power_offs"].sum()),
+                                power_ons=int(out["power_ons"].sum()))
             acc = out["acc"]
             return BatchResult(
                 names=[c.name for c in self.cells],
@@ -1863,9 +1879,7 @@ class BatchedSimulator:
                 pack_s=self.pack_s,
                 run_s=run_s,
                 spans=spans,
-                counters={
-                    "balance_trips": int(out["balance_trips"].max()),
-                    "drs_invocations": int(self._arrays["drs_mask"].sum())},
+                counters=counters,
                 timeseries=out.get("timeseries"),
                 tick_s=self._static.tick_s)
 
