@@ -92,7 +92,7 @@ import math
 import threading
 import time
 import warnings
-from typing import NamedTuple, Optional, Sequence
+from typing import Mapping, NamedTuple, Optional, Sequence
 
 import numpy as np
 
@@ -119,7 +119,7 @@ class BatchCell:
 
     name: str
     snapshot: ClusterSnapshot
-    traces: dict[str, DemandTrace]
+    traces: Mapping[str, DemandTrace]
     config: SimConfig
     powercap_enabled: bool = True            # False => Static/StaticHigh
     window: Optional[tuple[float, float]] = None

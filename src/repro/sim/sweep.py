@@ -149,13 +149,14 @@ def _specs_for(spec: SweepSpec) -> list[HostPowerSpec]:
 
 def _sweep_traces(spec: SweepSpec, base: np.ndarray, hot_host: np.ndarray,
                   phase_frac: np.ndarray, n_on: int,
-                  vm_ids: Sequence[str]) -> dict:
+                  vm_ids: Sequence[str]) -> workloads.TraceTable:
     """Vectorized demand-trace construction for one cell.
 
     Builds the whole cluster's ``(t0, cpu, mem)`` segment table as array
-    ops and hands it to :func:`workloads.traces_from_table` -- per-VM
-    factory calls dominated end-to-end cell construction at sweep scale.
-    Values are IEEE-identical to the scalar factories the loop used to
+    ops -- per-VM factory calls dominated end-to-end cell construction at
+    sweep scale.  The batched engine packs the table straight into a
+    ``TraceBank``; :func:`build_sweep` turns it into per-VM traces, whose
+    values are IEEE-identical to the scalar factories the loop used to
     call.
     """
     n, d = spec.n_vms, spec.duration_s
@@ -207,23 +208,18 @@ def _sweep_traces(spec: SweepSpec, base: np.ndarray, hot_host: np.ndarray,
             segs[z, 0, 1] = prime[z]
             segs[z, 1, 0] = (phase_frac[z] + 0.4) * d
             segs[z, 1, 1] = off[z]
-    return workloads.traces_from_table(vm_ids, segs, counts, periods)
+    return workloads.TraceTable(tuple(vm_ids), segs, counts, periods)
 
 
 def build_sweep(spec: SweepSpec, policy: str,
-                trace_memo: Optional[dict] = None,
                 vm_memo: Optional[dict] = None
                 ) -> tuple[ClusterSnapshot, dict, SimConfig]:
-    """Materialize one (spec, policy) cell.
+    """Materialize one (spec, policy) cell: its snapshot, ``{vm_id:
+    trace}`` and config.
 
     Deployment mirrors paper Table II: ``cpc``/``static`` spread the rack
     budget across every host; ``statichigh`` runs fewer hosts at their
     physical peak (the rest stay in standby with a zero cap).
-
-    ``trace_memo`` (scoped to one spec) shares the trace dict across the
-    policies whose deployment yields the same powered-on host count -- the
-    only placement fact the trace draw depends on -- so ``cpc``/``static``
-    build the cluster's traces once between them.
 
     ``vm_memo`` (scoped to one grid) shares the ``VirtualMachine`` list
     across every cell with the same (VM count, powered-on host sequence)
@@ -233,6 +229,14 @@ def build_sweep(spec: SweepSpec, policy: str,
     cells that customize VMs (the ``cap_blocked`` reservations) replace
     the affected entries copy-on-write instead of mutating.
     """
+    snap, table, cfg = _build_cell(spec, policy, vm_memo)
+    return snap, table.traces(), cfg
+
+
+def _build_cell(spec: SweepSpec, policy: str,
+                vm_memo: Optional[dict] = None
+                ) -> tuple[ClusterSnapshot, workloads.TraceTable, SimConfig]:
+    """:func:`build_sweep` with the cluster's demand as its segment table."""
     if spec.spike not in SPIKES:
         raise ValueError(f"unknown spike pattern {spec.spike!r}")
     if spec.churn not in CHURNS:
@@ -289,13 +293,8 @@ def build_sweep(spec: SweepSpec, policy: str,
                for v in range(spec.n_vms)]
         if vm_memo is not None:
             vm_memo[vm_key] = vms
-    if trace_memo is not None and n_on in trace_memo:
-        traces = trace_memo[n_on]
-    else:
-        traces = _sweep_traces(spec, base, hot_host, phase_frac, n_on,
-                               [vm.vm_id for vm in vms])
-        if trace_memo is not None:
-            trace_memo[n_on] = traces
+    table = _sweep_traces(spec, base, hot_host, phase_frac, n_on,
+                          [vm.vm_id for vm in vms])
 
     rules: list = []
     if spec.rules != "none":
@@ -371,7 +370,7 @@ def build_sweep(spec: SweepSpec, policy: str,
                     migration_bandwidth=(TIMED_BANDWIDTH
                                          if spec.timed else None),
                     power_events=power_events)
-    return snap, traces, cfg
+    return snap, table, cfg
 
 
 def _sweep_manager(policy: str,
@@ -471,8 +470,9 @@ def enable_compilation_cache() -> str:
 #: seconds}``, ``BatchResult.spans``) and in-scan ``counters``
 #: (``BatchResult.counters``).  Bucket 0's record also carries
 #: ``sweep_spans``, the call-level spans (``sweep``, ``sweep.build``,
-#: ``sweep.partition``, ``sweep.assemble``).  Benchmarks read it to report
-#: the cost split per bucket.
+#: ``sweep.partition``, ``sweep.assemble``), and, from ``run_sweep``,
+#: ``sweep_counters``: the trace banks built from segment tables and from
+#: per-VM traces.  Benchmarks read it to report the cost split per bucket.
 LAST_BATCH_INFO: list = []
 
 #: Worker threads for the overlapped pipeline: bucket N+1 packs and
@@ -614,31 +614,15 @@ def _run_buckets(cells, keys, sweep_id: int, sweep_spans: dict,
                          slot_slack=slot_slack)
 
 
-def _same_trace_specs(a: dict, b: dict, vm_ids: Sequence[str]) -> bool:
-    """True when two trace dicts compile to the identical ``TraceBank``:
-    every VM traced in both with structurally equal declarative specs
-    (``TraceSpec`` is a frozen dataclass).  Hand-written callables have no
-    spec and are never considered shareable."""
-    if a is b:                    # memoized across policies by build_sweep
-        return True
-    for vid in vm_ids:
-        sa = getattr(a.get(vid), "spec", None)
-        sb = getattr(b.get(vid), "spec", None)
-        if sa is None or sa != sb:
-            return False
-    return True
-
-
 @contextlib.contextmanager
 def _gc_pause():
     """Suspend cyclic garbage collection for a bounded construction phase.
 
-    Building a grid's cells allocates tens of thousands of long-lived
-    objects in one burst (VM dataclasses, trace closures, segment
-    tuples); the allocation spike trips repeated full collections that
-    rescan the entire heap -- jax's module graph included -- without ever
-    finding reclaimable cycles, and those scans dominated end-to-end
-    sweep wall time.  Collection resumes (if it was on) when the phase
+    Building a grid's cells allocates thousands of long-lived objects in
+    one burst (snapshots, hosts, VM dataclasses); the allocation spike
+    trips repeated full collections that rescan the entire heap -- jax's
+    module graph included -- without ever finding reclaimable cycles, and
+    those scans dominated end-to-end sweep wall time.  Collection resumes (if it was on) when the phase
     ends; nothing built here is cyclic garbage."""
     was = gc.isenabled()
     gc.disable()
@@ -650,47 +634,47 @@ def _gc_pause():
 
 
 def _build_batch_cells(specs: Sequence[SweepSpec],
-                       policies: Sequence[str]):
+                       policies: Sequence[str],
+                       counters: Optional[dict] = None):
     """Materialize the grid's cells, packing each spec's ``TraceBank`` once.
 
-    Policies of one spec usually share identical traces (`cpc`/`static`
+    Each bank is packed straight from the cluster's segment table
+    (``TraceBank.from_table``): no per-VM trace object is made, and a
+    cell's ``traces`` makes its callables only if something reads them.
+    Policies of one spec usually share identical tables (`cpc`/`static`
     always do; `statichigh` differs only when the trace draw depends on the
-    powered-on host count), so the bank -- the per-VM step-function
-    compilation that dominated per-cell host-side packing -- is built for
-    the first policy and reused wherever the specs compare equal, across
-    policies and whatever pad bucket the cell later lands in.
+    powered-on host count), so the bank is built for the first policy and
+    reused wherever the tables compare equal, across policies and whatever
+    pad bucket the cell later lands in.  ``counters["banks_from_table"]``
+    counts the banks built.
 
     Construction itself is shared at two further levels, legal because
     the batched engine treats cell snapshots as read-only pack sources:
     ``policy`` only influences deployment through the ``statichigh``
     branch, so every spread-deployment policy (`cpc`/`static`) of one
-    spec reuses a single ``build_sweep`` result (one snapshot, one trace
-    dict, one bank for two cells), and a grid-wide ``vm_memo`` shares the
-    ``VirtualMachine`` list across all cells with the same (VM count,
-    powered-on hosts) -- host-side scenario construction sits on the
-    end-to-end critical path the ``sweep_e2e`` bench clocks.
+    spec reuses a single build (one snapshot, one table, one bank for two
+    cells), and a grid-wide ``vm_memo`` shares the ``VirtualMachine`` list
+    across all cells with the same (VM count, powered-on hosts) --
+    host-side scenario construction sits on the end-to-end critical path.
     """
     from repro.sim.batch import BatchCell
-    from repro.sim.workloads import TraceBank
     cells, keys = [], []
     vm_memo: dict = {}
     with _gc_pause():
         for spec in specs:
-            bank, bank_traces = None, None
-            memo: dict = {}
-            built: dict = {}            # deployment class -> build_sweep()
+            table = bank = None
+            built: dict = {}            # deployment class -> _build_cell()
             for p in policies:
                 dep = "statichigh" if p == "statichigh" else "spread"
                 if dep not in built:
-                    built[dep] = build_sweep(spec, p, trace_memo=memo,
-                                             vm_memo=vm_memo)
+                    snap, t, cfg = _build_cell(spec, p, vm_memo=vm_memo)
+                    built[dep] = (snap, workloads.TableTraces(t), cfg)
                 snap, traces, cfg = built[dep]
-                vm_ids = list(snap.vms)
-                if (bank is None or bank.vm_order != vm_ids
-                        or not _same_trace_specs(bank_traces, traces,
-                                                 vm_ids)):
-                    bank = TraceBank.from_traces(traces, vm_ids)
-                    bank_traces = traces
+                if table is None or not traces.table.same_demand(table):
+                    table = traces.table
+                    bank = table.bank()
+                    if counters is not None:
+                        counters["banks_from_table"] += 1
                 cells.append(BatchCell(
                     name=f"{spec.name}/{p}", snapshot=snap, traces=traces,
                     config=cfg, powercap_enabled=(p == "cpc"),
@@ -728,15 +712,19 @@ def run_sweep(specs: Sequence[SweepSpec],
     input ``specs`` x ``policies`` order, whatever the partitioning.
 
     Each batched call takes a fresh ``sweep`` id and records its host spans
-    (``repro.sim.spans``) into :data:`LAST_BATCH_INFO`.
+    (``repro.sim.spans``) into :data:`LAST_BATCH_INFO`, and in bucket 0's
+    ``sweep_counters`` how many trace banks it built from segment tables
+    (``banks_from_table``) and from per-VM traces (``banks_from_traces``,
+    one per vector-engine fallback cell).
     """
     if engine == "batch":
         from repro.sim.batch import BatchedSimulator, BatchUnsupported
         LAST_BATCH_INFO.clear()
         sweep_id, record = next_sweep_id(), {}
+        counters = {"banks_from_table": 0, "banks_from_traces": 0}
         with span("sweep", record, sweep=sweep_id):
             with span("sweep.build", record, sweep=sweep_id):
-                cells, keys = _build_batch_cells(specs, policies)
+                cells, keys = _build_batch_cells(specs, policies, counters)
             with span("sweep.partition", record, sweep=sweep_id):
                 reasons = BatchedSimulator.unsupported_cells(
                     cells, _grid_balancer(specs))
@@ -762,10 +750,17 @@ def run_sweep(specs: Sequence[SweepSpec],
             with span("sweep.assemble", record, sweep=sweep_id):
                 out: dict[str, dict[str, SweepCellResult]] = {}
                 for spec in specs:
-                    out[spec.name] = {
-                        p: flat.get((spec.name, p))
-                        or run_cell(spec, p, engine="vector")
-                        for p in policies}
+                    out[spec.name] = {}
+                    for p in policies:
+                        res = flat.get((spec.name, p))
+                        if res is None:
+                            # The vector engine packs the cell's bank from
+                            # its per-VM traces.
+                            counters["banks_from_traces"] += 1
+                            res = run_cell(spec, p, engine="vector")
+                        out[spec.name][p] = res
+        if LAST_BATCH_INFO:
+            LAST_BATCH_INFO[0]["sweep_counters"] = counters
         return out
     out = {}
     for spec in specs:

@@ -6,11 +6,17 @@ per-object simulator, and additionally attaches a declarative ``spec``
 function.  :class:`TraceBank` compiles a whole cluster's specs into padded
 arrays so the vectorized engine evaluates every VM's demand at time ``t``
 with one ``searchsorted``-style pass instead of a Python call per VM.
+
+Generators that build a whole cluster at once produce a :class:`TraceTable`
+of segments instead: the batched engine packs it straight into a bank
+(:meth:`TraceBank.from_table`), and :class:`TableTraces` makes the per-VM
+callables only for a caller that asks for them.
 """
 
 from __future__ import annotations
 
 import dataclasses
+from collections.abc import Mapping
 from typing import Callable, Optional, Sequence
 
 import numpy as np
@@ -91,6 +97,73 @@ def traces_from_table(names: Sequence[str], segs: np.ndarray,
         out[name] = spec_trace(TraceSpec(
             segments=tuple(tuple(s) for s in row), period=p))
     return out
+
+
+def _bank_period(periods) -> np.ndarray:
+    """A bank's period column: ``inf`` where a trace is aperiodic."""
+    p = np.asarray(periods, dtype=np.float64)
+    return np.where(np.isfinite(p), p, np.inf)
+
+
+@dataclasses.dataclass(frozen=True, eq=False)
+class TraceTable:
+    """A cluster's demand traces as the arrays :func:`traces_from_table`
+    takes: ``segs`` ``(n, k, 3)`` rows of ``(t0, cpu_mhz, mem_mb)``, the
+    first ``counts[i]`` (1 to ``k``) of row ``i`` valid, ``periods``
+    non-finite where a row is aperiodic; row ``i`` traces ``vm_ids[i]``."""
+
+    vm_ids: tuple
+    segs: np.ndarray
+    counts: np.ndarray
+    periods: np.ndarray
+
+    def traces(self) -> dict[str, DemandTrace]:
+        return traces_from_table(self.vm_ids, self.segs, self.counts,
+                                 self.periods)
+
+    def bank(self) -> "TraceBank":
+        return TraceBank.from_table(self.vm_ids, self.segs, self.counts,
+                                    self.periods)
+
+    def same_demand(self, other: "TraceTable") -> bool:
+        """True when both tables trace the same VMs with the same segments
+        and periods, so that they compile to the same bank: what is past a
+        row's ``counts`` is ignored, and all non-finite periods are one."""
+        if self is other:
+            return True
+        if (self.vm_ids != other.vm_ids
+                or not np.array_equal(self.counts, other.counts)
+                or not np.array_equal(_bank_period(self.periods),
+                                      _bank_period(other.periods))):
+            return False
+        w = int(self.counts.max(initial=0))
+        valid = np.arange(w)[None, :] < self.counts[:, None]
+        return np.array_equal(self.segs[:, :w][valid],
+                              other.segs[:, :w][valid])
+
+
+class TableTraces(Mapping):
+    """``{vm_id: trace}`` of a :class:`TraceTable`, made on first use.
+    The batched engine reads the table's bank, so a batched sweep never
+    makes the per-VM callables."""
+
+    def __init__(self, table: TraceTable):
+        self.table = table
+        self._traces: Optional[dict[str, DemandTrace]] = None
+
+    def _made(self) -> dict[str, DemandTrace]:
+        if self._traces is None:
+            self._traces = self.table.traces()
+        return self._traces
+
+    def __getitem__(self, vm_id: str) -> DemandTrace:
+        return self._made()[vm_id]
+
+    def __iter__(self):
+        return iter(self._made())
+
+    def __len__(self) -> int:
+        return len(self.table.vm_ids)
 
 
 def constant(cpu_mhz: float, mem_mb: float) -> DemandTrace:
@@ -215,6 +288,30 @@ class TraceBank:
             bank.bps = bps
             bank.cpu_vals = cpu
             bank.mem_vals = mem
+        return bank
+
+    @classmethod
+    def from_table(cls, vm_order: Sequence[str], segs: np.ndarray,
+                   counts: np.ndarray, periods: np.ndarray) -> "TraceBank":
+        """The bank of :func:`traces_from_table`'s traces over ``vm_order``,
+        the table's row order, built from the arrays with no per-VM
+        object: bit for bit what :meth:`from_traces` gives for them."""
+        bank = cls(vm_order)
+        segs = np.asarray(segs, dtype=np.float64)
+        counts = np.asarray(counts, dtype=np.int64)
+        n = segs.shape[0]
+        if n:
+            cols = np.arange(int(counts.max()))[None, :]
+            # Padding repeats the last valid segment's values, as in
+            # ``from_traces``; its breakpoints are ``inf``.
+            src = np.minimum(cols, counts[:, None] - 1)
+            rows = np.arange(n)[:, None]
+            bank.rows = np.arange(n, dtype=np.int64)
+            bank.period = _bank_period(periods)
+            bank.bps = np.where(cols < counts[:, None], segs[rows, src, 0],
+                                np.inf)
+            bank.cpu_vals = segs[rows, src, 1]
+            bank.mem_vals = segs[rows, src, 2]
         return bank
 
     def eval(self, t: float) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
